@@ -60,7 +60,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, Iterable, List, Optional, Union
+from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple, Union
 
 from repro.core import flow
 from repro.core.flow import Channel, StageFuture
@@ -70,6 +70,11 @@ from repro.staging.ports import TaskStagingView, decode_refs, encode_refs
 from repro.staging.store import StagedRef
 
 _MISSING = object()
+
+
+def _latest(a: Optional[float], b: Optional[float]) -> Optional[float]:
+    """The later of two optional times."""
+    return a if b is None or (a is not None and a >= b) else b
 
 
 @dataclass
@@ -215,6 +220,7 @@ class Stage:
         self.bound_inputs: Dict[str, Any] = {}   # channel ports, concrete
         self._future_ports: List = []            # (port, StageFuture), lazy
         self._port_deps: List[str] = []          # producer task names
+        self._input_at: Optional[float] = None   # latest bound put (traced)
 
     def add(self, task: Union[TaskSpec, Kernel]) -> TaskSpec:
         spec = task if isinstance(task, TaskSpec) else TaskSpec(task)
@@ -321,6 +327,10 @@ class AppManager:
         # counted (-> LookupError mid-bind)
         self._advance_depth = 0
         self._pending_wakes: List[Any] = []
+        # traced runs: (channel, producer key) -> put time on the run's
+        # clock, so a consumer's runtime.launch starts at its input's
+        # arrival (task.meta["t_input"])
+        self._put_at: Dict[Tuple[str, str], float] = {}
 
     # ------------------------------------------------------------ build
     def _make_run(self, kernel: Kernel, stage: Stage):
@@ -378,7 +388,8 @@ class AppManager:
         # stage_idx keeps auto-names unique when a stage NAME repeats
         # across appended cycles (the adaptive extension pattern)
         name = spec.name or f"{pr.name}.{stage_idx:04d}.{stage_label}.{j:05d}"
-        port_deps = self._bind_task_ports(spec, pr, name, stage_idx, j)
+        port_deps, input_at = self._bind_task_ports(spec, pr, name,
+                                                    stage_idx, j)
         all_deps = list(dict.fromkeys(
             [*deps, *stage._port_deps, *port_deps]))
         # deferred import: repro.serving sits above core in the layering
@@ -391,6 +402,9 @@ class AppManager:
                  iteration=int(spec.metadata.get("iteration", 0)),
                  idempotent=k.idempotent, priority=priority)
         t.meta["pipeline"] = pr.name
+        input_at = _latest(stage._input_at, input_at)
+        if input_at is not None:
+            t.meta["t_input"] = input_at
         if spec.sla is not None:
             t.meta["sla"] = spec.sla
         if deadline is not None:
@@ -552,7 +566,9 @@ class AppManager:
         return credit
 
     def _take(self, ch: Channel, ck: str, stream: Optional[str] = None,
-              n_consumers: int = 1) -> Any:
+              n_consumers: int = 1) -> Tuple[Any, Optional[float]]:
+        """Take one put from ``ch`` for consumer ``ck``; returns the value
+        and, in a traced run, the time the put arrived (else ``None``)."""
         pk = self._replayed_takes.get((ch.name, ck))
         producer, value = ch.take(ck, pk, stream)
         is_ref = isinstance(value, StagedRef)
@@ -564,34 +580,42 @@ class AppManager:
                                  broadcast=ch.mode == "broadcast")
         # a take frees channel space: wake producers parked on capacity
         self._wake(("channel_space", ch.name))
-        return value
+        return value, (self._put_at.get((ch.name, producer))
+                       if self._put_at else None)
 
     def _bind_stage_inputs(self, stage: Stage, pr: _PipelineRun, idx: int):
         stage.bound_inputs = {}
         stage._future_ports = []
         stage._port_deps = []
+        stage._input_at = None
         for port, src in flow.normalize_sources(stage.inputs).items():
             if isinstance(src, Channel):
                 ck = f"{pr.name}:{idx:04d}:{port}"
-                stage.bound_inputs[port] = self._take(
+                stage.bound_inputs[port], at = self._take(
                     src, ck, f"{pr.name}:{port}",
                     n_consumers=len(stage.tasks))
+                stage._input_at = _latest(stage._input_at, at)
             else:
                 stage._future_ports.append((port, src))
                 stage._port_deps.extend(src.stage.task_names)
 
     def _bind_task_ports(self, spec: TaskSpec, pr: _PipelineRun, name: str,
-                         idx: int, j: int) -> List[str]:
+                         idx: int, j: int
+                         ) -> Tuple[List[str], Optional[float]]:
+        """Bind one task's own ports: returns its future deps and, in a
+        traced run, the latest arrival of the channel puts it took."""
         port_deps: List[str] = []
+        input_at = None
         for port, src in flow.normalize_sources(spec.inputs).items():
             if isinstance(src, Channel):
                 ck = f"{pr.name}:{idx:04d}:{j:05d}:{port}"
-                self._task_bound.setdefault(name, {})[port] = \
+                self._task_bound.setdefault(name, {})[port], at = \
                     self._take(src, ck, f"{pr.name}:{j:05d}:{port}")
+                input_at = _latest(input_at, at)
             else:
                 self._task_futures.setdefault(name, []).append((port, src))
                 port_deps.extend(src.stage.task_names)
-        return port_deps
+        return port_deps, input_at
 
     # ------------------------------------------------------------ staging
     def _build_staging_manifest(self, t: Task, spec: TaskSpec,
@@ -674,6 +698,9 @@ class AppManager:
                 if ref is not None:
                     value = ref
         is_ref = isinstance(value, StagedRef)
+        if getattr(self.runtime, "tracer", None) is not None \
+                and self.session is not None:
+            self._put_at[(ch.name, pk)] = self.session._now()
         ch.put(pk, value, task_level=task_level,
                check=check and not is_ref,
                nbytes=value.nbytes if is_ref else int(nbytes_hint or 0))

@@ -233,7 +233,10 @@ def load_segments(path: str) -> List[Segment]:
 
 def segment_from_tracer(tracer) -> Segment:
     """Build a Segment from a live :class:`~repro.obs.tracer.Tracer` —
-    the no-journal path for Chrome export (``prof`` in hand, no file)."""
+    the no-journal path for Chrome export (``prof`` in hand, no file).
+    Only task attempts and parks enter it: program spans (engine, member,
+    runtime, compile) are phases the slot timeline already covers, and
+    leave its window as it is."""
     seg = Segment(0)
     seg.clock = "vt" if tracer.clock == "virtual" else "wall"
     for sp in tracer.spans:
@@ -253,6 +256,8 @@ def segment_from_tracer(tracer) -> Segment:
             seg.parks.append({"pipeline": sp.get("pipeline"),
                               "on": sp.get("on"),
                               "t0": sp["t0"], "t1": sp["t1"]})
+        else:
+            continue
         seg.w0 = min(seg.w0, sp["t0"])
         seg.w1 = max(seg.w1, sp["t1"])
     for ev in tracer.events:

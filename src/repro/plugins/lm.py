@@ -67,6 +67,19 @@ def _device_of(tree) -> str:
 
 @register_kernel("lm.train", description="train an LM for n steps")
 def lm_train(args, ctx):
+    """Train member ``args["member"]`` for ``args["steps"]`` steps.  With
+    the run's tracer: a ``member.train`` span over the body, with a
+    ``member.data`` child per step's batch and placement and a
+    ``member.wait`` child around each blocking read-back; step dispatch
+    is what the children leave of it."""
+    tr = getattr(ctx.get("runtime"), "tracer", None)
+    if tr is None:
+        return _train(args, ctx, None)
+    with tr.span("member", "train", member=int(args.get("member", 0))):
+        return _train(args, ctx, tr)
+
+
+def _train(args, ctx, tr):
     cfg = resolve_cfg(args.get("arch", "reduced:gemma2-2b"))
     hyper = TrainHyper(base_lr=float(args.get("lr", 3e-4)), warmup=2,
                        total_steps=int(args.get("total_steps", 1000)),
@@ -81,16 +94,33 @@ def lm_train(args, ctx):
     step = _steps(cfg, "train", hyper)
     data = SyntheticLM(cfg, _shape(args, cfg),
                        seed=int(args.get("data_seed", 0)))
+    if tr is not None:
+        wait = tr.open("member", "wait")
     start = int(jax.device_get(state["step"]))
+    if tr is not None:
+        tr.close(wait)
     losses = []
     for i in range(int(args.get("steps", 2))):
-        state, m = step(state, _put(data.batch_at(start + i), sh))
+        if tr is not None:
+            prep = tr.open("member", "data")
+        batch = _put(data.batch_at(start + i), sh)
+        if tr is not None:
+            tr.close(prep)
+        state, m = step(state, batch)
         losses.append(m["loss"])
     STATE_STORE[sid] = state
+    if tr is not None:
+        wait = tr.open("member", "wait")
     losses = [float(x) for x in jax.device_get(losses)]
+    if tr is not None:
+        tr.close(wait)
+        wait = tr.open("member", "wait")
+    end = int(jax.device_get(state["step"]))
+    if tr is not None:
+        tr.close(wait)
     return {"loss": losses[-1] if losses else float(np.nan),
             "losses": losses,
-            "step": int(jax.device_get(state["step"])),
+            "step": end,
             "member": sid[1],
             "impl": flags.TRAIN_IMPL,
             "device": _device_of(state["params"])}

@@ -62,6 +62,10 @@ def serve_source(args, ctx):
 @register_kernel("serve.decode",
                  description="continuous-batch decode one traffic window")
 def serve_decode(args, ctx):
+    """Serve one window's requests; returns counts, the engine's
+    ``stats`` and, per request, its ``rid`` and the times its first token
+    was read back and it was done, on the run's tracer's clock (on
+    ``time.perf_counter`` in an untraced run)."""
     from repro.plugins.lm import submesh_sharding
     from repro.serve import BatchedServer, Request
     from repro.serving.traffic import TrafficModel
@@ -77,7 +81,8 @@ def serve_decode(args, ctx):
     max_new = max(r.max_new_tokens for r in reqs)
     srv = BatchedServer(cfg, params,
                         batch=int(args.get("decode_slots", 4)),
-                        prompt_len=S0, max_len=S0 + max_new)
+                        prompt_len=S0, max_len=S0 + max_new,
+                        tracer=getattr(ctx.get("runtime"), "tracer", None))
     srv.submit([Request(rid=r.rid,
                         prompt=np.random.default_rng(r.rid).integers(
                             0, cfg.vocab_size, S0),
@@ -86,4 +91,6 @@ def serve_decode(args, ctx):
     done = srv.run()
     return {"served": len(done),
             "tokens": sum(len(r.out_tokens) for r in done),
-            "stats": srv.stats}
+            "stats": srv.stats,
+            "requests": [{"rid": r.rid, "first_token_at": r.first_token_at,
+                          "done_at": r.done_at} for r in done]}

@@ -466,6 +466,8 @@ class RuntimeSession:
         # and returns without touching the accounting
         self._live_attempts: Dict[tuple, tuple] = {}
         self._zombie_threads: set = set()
+        # (task name, attempt) -> open runtime.launch span (traced runs)
+        self._launches: Dict[tuple, Any] = {}
         # journal replay set, loaded once per session
         self._replayed_done, self._replayed_results, \
             self._replayed_history = runtime.journal.load_state()
@@ -498,10 +500,16 @@ class RuntimeSession:
     # ------------------------------------------------------- observability
     def _now(self) -> float:
         """The run's authoritative clock: virtual now in sim mode, wall
-        seconds since the first drain in real mode (0.0 before it)."""
+        seconds since the first drain in real mode (0.0 before it) — the
+        tracer's clock when one is attached, so that the runtime's and
+        the program's spans share one origin."""
         if self.rt.mode == "sim":
             return self.vnow
-        return 0.0 if self._t0 is None else time.perf_counter() - self._t0
+        if self._t0 is None:
+            return 0.0
+        if self.tracer is not None:
+            return self.tracer.now()
+        return time.perf_counter() - self._t0
 
     def _register_gauges(self):
         """Built-in gauges over live session state, sampled by the drain
@@ -664,7 +672,13 @@ class RuntimeSession:
             self._drain_sim()
             self.prof.ttc = self.vnow
         else:
-            self._drain_real()
+            tr = self.tracer
+            if tr is None:
+                self._drain_real()
+            else:
+                tr.start_clock(self._t0)
+                with tr.compile_spans():
+                    self._drain_real()
             self.prof.ttc = time.perf_counter() - self._t0
         self.prof.n_tasks = len(self.graph)
         self.prof.n_failed = sum(1 for t in self.graph.tasks.values()
@@ -1334,7 +1348,10 @@ class RuntimeSession:
 
     def _execute_real(self, t: Task):
         rt, prof, cv = self._rt_for(t), self.prof, self._cv
+        tr = self.tracer
         epoch = t.meta.get("launch_epoch")
+        if tr is not None:
+            tr.bind_attempt(t.name, t.attempts)
         t.t_started = time.perf_counter()
         outcome = TaskState.DONE
         t.meta.pop("t_data_kernel", None)     # fresh window per attempt
@@ -1347,6 +1364,10 @@ class RuntimeSession:
             # keeps t_exec and t_data disjoint in the TTC decomposition
             t.meta["t_data_attempt"] = rt._stage_in_task(t)
             t.t_started = time.perf_counter()
+            if tr is not None:
+                launch = self._launches.pop((t.name, t.attempts), None)
+                if launch is not None:
+                    tr.close(launch)
             if t.run is not None:
                 # held locally until past the zombie check below: an
                 # abandoned attempt's late return must not clobber the
@@ -1371,6 +1392,9 @@ class RuntimeSession:
                 # history — this completion is a zombie
                 cv.notify_all()
                 return
+            if tr is not None:
+                settle = tr.open("runtime", "settle", stacked=False,
+                                 parent=(t.name, t.attempts))
             pod = rt._task_pod(t)
             if t.run is not None and outcome == TaskState.DONE:
                 t.result = res
@@ -1398,16 +1422,20 @@ class RuntimeSession:
                 t_data=t.meta.get("t_data_attempt", 0.0),
                 t_data_kernel=t.meta.get("t_data_kernel", 0.0),
                 wall=max(t.t_finished - t.t_started, 0.0))
-            if self.tracer is not None:
-                self.tracer.task_end(
-                    t, self._now(),
-                    "done" if t.state == TaskState.DONE else "failed")
+            if tr is not None:
+                # the moment dependents (and this task's retry) became
+                # runnable: where their runtime.launch spans start
+                t.meta["t_settled"] = now = self._now()
+                tr.task_end(t, now,
+                            "done" if t.state == TaskState.DONE else "failed")
             if t.state.terminal:
                 # cumulative across attempts, charged once at the end
                 prof.t_data += t.t_data
                 rt._staging_finish(t)
                 self._queue_callback(t)
             self._inflight -= 1
+            if tr is not None:
+                tr.close(settle)
             cv.notify_all()
 
     def _drain_real(self):
@@ -1446,12 +1474,31 @@ class RuntimeSession:
         rt.journal.record(t, "scheduled", pod=pod, **self._sched_extra(t))
         if self.tracer is not None:
             self.tracer.task_begin(t, self._now(), pod=pod)
+            self._open_launch(t)
         self._inflight += 1
         th = threading.Thread(target=self._execute_real,
                               args=(t,), daemon=True)
         self._live_attempts[(t.name, t.attempts)] = (th, t)
         workers.append(th)
         th.start()
+
+    def _open_launch(self, t: Task):
+        """Open the attempt's ``runtime.launch`` span, back-dated to when
+        it became runnable: the latest of its dependencies' settling, the
+        arrival of the channel items it consumes (``t_input``, stamped by
+        AppManager) and its own previous attempt's settling.  An attempt
+        with none of these gets no span.  The kernel's worker closes it
+        at the kernel's first line (:meth:`_execute_real`); its profiler
+        mirror starts here, as the drain thread launches the attempt."""
+        tasks = self.graph.tasks
+        marks = [tasks[d].meta.get("t_settled") for d in t.deps]
+        marks += [t.meta.get("t_input"), t.meta.get("t_settled")]
+        marks = [m for m in marks if m is not None]
+        if marks:
+            self._launches[(t.name, t.attempts)] = self.tracer.open(
+                "runtime", "launch", t0=max(marks), stacked=False,
+                parent=(t.name, t.attempts), task=t.name,
+                attempt=t.attempts)
 
     def _drain_real_loop(self, workers: List[threading.Thread]):
         rt, graph, prof = self.rt, self.graph, self.prof

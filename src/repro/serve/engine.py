@@ -58,6 +58,7 @@ class Request:
     max_new_tokens: int = 16
     out_tokens: List[int] = field(default_factory=list)
     submitted_at: float = 0.0
+    first_token_at: float = 0.0      # its first token on the host
     done_at: float = 0.0
     sla: str = "throughput"          # serving SLA class (repro.serving.sla)
 
@@ -87,17 +88,30 @@ class BatchedServer:
     configs containing them fall back to the legacy synchronized-wave loop
     (evict-at-own-length still holds; no mid-wave admission).
 
-    ``clock`` stamps Request.submitted_at/done_at: ``time.perf_counter``
-    in real runs, a virtual-time callable in DES runs (repro.serving).
-    ``prefill_fn``/``step_fn`` let tests inject deterministic stand-ins
-    for the jitted model functions.
+    ``clock`` stamps Request.submitted_at/first_token_at/done_at: the
+    tracer's clock when a ``tracer`` (repro.obs.Tracer) is given, else
+    ``time.perf_counter``; a virtual-time callable in DES runs
+    (repro.serving).  ``prefill_fn``/``step_fn`` let tests inject
+    deterministic stand-ins for the jitted model functions.
+
+    With a ``tracer``, continuous batching records program spans:
+    ``engine.build`` (this constructor), ``engine.admit`` (an admission
+    with joiners: prefill dispatch, row merge, first-token read-back),
+    ``engine.decode`` (one decode step after admission, through its
+    bookkeeping), ``engine.wait`` (each blocking read-back, inside those
+    two) and ``engine.request`` (submit to eviction, per ``rid``).
     """
 
     def __init__(self, cfg: ModelConfig, params, *, batch: int, prompt_len: int,
-                 max_len: int, mesh=None, clock=time.perf_counter,
-                 prefill_fn=None, step_fn=None):
+                 max_len: int, mesh=None, clock=None,
+                 prefill_fn=None, step_fn=None, tracer=None):
+        self.tracer = tracer
+        if tracer is not None:
+            build = tracer.open("engine", "build")
         self.cfg, self.params, self.mesh = cfg, params, mesh
         self.B, self.S0, self.Smax = batch, prompt_len, max_len
+        if clock is None:
+            clock = time.perf_counter if tracer is None else tracer.now
         self.clock = clock
         # sliding-window ring caches are batch-synchronized -> wave mode
         self.continuous = not (cfg.sliding_window and any(
@@ -126,6 +140,8 @@ class BatchedServer:
         self.queue: collections.deque = collections.deque()
         self.stats = {"served": 0, "decode_steps": 0, "prefills": 0,
                       "slot_steps": 0}
+        if tracer is not None:
+            tracer.close(build)
 
     def submit(self, reqs: List[Request]):
         for r in reqs:
@@ -152,6 +168,10 @@ class BatchedServer:
                 joiners.append(i)
         if not joiners:
             return cache
+        tr = self.tracer
+        if tr is not None:
+            admit = tr.open("engine", "admit", joiners=len(joiners),
+                            rids=[slots[i].rid for i in joiners])
         joinset = set(joiners)
         tokens = jnp.stack(
             [jnp.asarray(slots[i].prompt[:self.S0])
@@ -172,10 +192,19 @@ class BatchedServer:
                 merged["tail"] = _merge_rows(
                     cache["tail"], fresh["tail"], mask, axis=0)
             cache = merged
-        first = jax.device_get(jnp.argmax(out["logits"][:, 0], axis=-1))
+        first = jnp.argmax(out["logits"][:, 0], axis=-1)
+        if tr is not None:
+            wait = tr.open("engine", "wait")
+        first = jax.device_get(first)
+        if tr is not None:
+            tr.close(wait)
+        now = self.clock()
         for i in joiners:
             last[i] = int(first[i])
             positions[i] = self.S0
+            slots[i].first_token_at = now
+        if tr is not None:
+            tr.close(admit)
         return cache
 
     def _run_continuous(self) -> List[Request]:
@@ -184,13 +213,23 @@ class BatchedServer:
         positions = [0] * self.B     # next cache write offset per slot
         last = [0] * self.B          # last decoded token per slot (host)
         cache = None
+        tr = self.tracer
         while self.queue or any(s is not None for s in slots):
             cache = self._admit(slots, cache, positions, last)
+            if tr is not None:
+                step = tr.open("engine", "decode", slots=sum(
+                    s is not None for s in slots))
+                evicted = len(done)
             logits, cache = self.step(
                 self.params, cache, jnp.asarray(last, jnp.int32)[:, None],
                 jnp.asarray(positions, jnp.int32))
             self.stats["decode_steps"] += 1
-            nxt = jax.device_get(jnp.argmax(logits[:, 0], axis=-1))
+            nxt = jnp.argmax(logits[:, 0], axis=-1)
+            if tr is not None:
+                wait = tr.open("engine", "wait")
+            nxt = jax.device_get(nxt)
+            if tr is not None:
+                tr.close(wait)
             for i, r in enumerate(slots):
                 if r is None:
                     continue
@@ -203,6 +242,12 @@ class BatchedServer:
                     done.append(r)
                     self.stats["served"] += 1
                     slots[i] = None      # evict: slot free next admission
+            if tr is not None:
+                tr.close(step)
+                for r in done[evicted:]:
+                    tr.record("engine", "request", r.submitted_at,
+                              r.done_at, rid=r.rid,
+                              first_token_at=r.first_token_at)
         return done
 
     # -------------------------------------------------- legacy wave loop
@@ -226,6 +271,10 @@ class BatchedServer:
                 last = jnp.argmax(logits[:, 0], axis=-1)
                 self.stats["decode_steps"] += 1
                 host = jax.device_get(last)
+                if t == 0:           # the wave's first tokens on the host
+                    now = self.clock()
+                    for r in wave:
+                        r.first_token_at = now
                 for i, r in enumerate(wave):
                     if t < r.max_new_tokens:
                         r.out_tokens.append(int(host[i]))

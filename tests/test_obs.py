@@ -300,3 +300,255 @@ def test_metrics_decimation_keeps_timeline_bounded():
     assert s["n_samples"] == len(s["t"]) == len(s["gauges"]["x"])
     # decimation keeps the earliest and tracks the latest region
     assert s["t"][0] == 0.0 and s["t"][-1] > 5_000
+
+
+# ------------------------------------------------------- program spans
+def _kernel_spans(cat):
+    """Register (once) a kernel that opens a ``<cat>.outer`` span with a
+    ``<cat>.inner`` child and jits a fresh function inside it."""
+    from repro.core.kernel_plugin import _KERNEL_REGISTRY, register_kernel
+    name = "test.obs.spans"
+    if name not in _KERNEL_REGISTRY:
+        @register_kernel(name, description="program spans + a fresh jit")
+        def spans(args, ctx):
+            import jax
+            import jax.numpy as jnp
+            tr = ctx["runtime"].tracer
+            if tr is None:
+                return float(jax.jit(lambda x: x + args["k"])(
+                    jnp.ones(2)).sum())
+            with tr.span(args["cat"], "outer", k=args["k"]):
+                with tr.span(args["cat"], "inner"):
+                    return float(jax.jit(lambda x: x + args["k"])(
+                        jnp.ones(2)).sum())
+    return name
+
+
+def _span_pipes(stages=2, width=2, cat="probe"):
+    from repro.core import Kernel, PipelineSpec, Stage, TaskSpec
+    name = _kernel_spans(cat)
+    out = []
+    for s in range(stages):
+        specs = []
+        for j in range(width):
+            k = Kernel(name)
+            k.arguments = {"cat": cat, "k": 10 * s + j}
+            specs.append(TaskSpec(k, name=f"p.s{s}.t{j}"))
+        out.append(Stage(specs, name=f"s{s}"))
+    return [PipelineSpec(out, name="p")]
+
+
+def test_span_parents_on_concurrent_threads():
+    """Each thread's spans nest under that thread's open span, whatever
+    the others do meanwhile; a thread bound to a task attempt parents its
+    outermost span to the attempt."""
+    import threading
+    tr = Tracer()
+    n = 8
+    gate = threading.Barrier(n)
+    done = []
+
+    def work(i):
+        if i % 2:
+            tr.bind_attempt(f"t{i}", 1)
+        with tr.span("probe", "outer", i=i) as outer:
+            gate.wait(timeout=10)
+            with tr.span("probe", "inner", i=i) as inner:
+                gate.wait(timeout=10)
+                tr.record("probe", "mark", 0.0, 0.0, i=i)
+        done.append((outer, inner))
+
+    threads = [threading.Thread(target=work, args=(i,)) for i in range(n)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join(timeout=20)
+    assert not any(th.is_alive() for th in threads) and len(done) == n
+    by = {(s["name"], s["i"]): s for s in tr.spans}
+    assert len({s["id"] for s in tr.spans}) == 3 * n
+    for i in range(n):
+        outer, inner = by[("outer", i)], by[("inner", i)]
+        assert outer["parent"] == ((f"t{i}", 1) if i % 2 else None)
+        assert inner["parent"] == outer["id"]
+        assert by[("mark", i)]["parent"] == inner["id"]
+        assert outer["t0"] <= inner["t0"] <= inner["t1"] <= outer["t1"]
+
+
+def test_close_ends_spans_left_open_above_it():
+    tr = Tracer()
+    outer = tr.open("probe", "outer")
+    tr.open("probe", "leaked")          # a raise skipped its close
+    tr.close(outer)
+    after = tr.open("probe", "next")
+    tr.close(after)
+    names = [s["name"] for s in tr.spans]
+    assert names == ["outer", "next"]
+    assert tr.spans[1]["parent"] is None
+
+
+def test_span_outcome_when_the_block_raises():
+    tr = Tracer()
+    with pytest.raises(ValueError):
+        with tr.span("probe", "body"):
+            raise ValueError("x")
+    assert tr.spans[0]["outcome"] == "failed"
+
+
+def test_real_drain_program_spans_share_the_runtime_clock():
+    """A kernel's outermost span is parented to its task attempt and lies
+    inside the attempt's span; runtime.launch runs from the last
+    dependency's settling to the kernel's first line; one runtime.settle
+    per attempt; the executor fixed the tracer's origin."""
+    from repro.core import AppManager
+    tr = Tracer()
+    am = AppManager(PilotRuntime(slots=2, mode="real", tracer=tr))
+    prof = am.run(_span_pipes())
+    assert prof.n_failed == 0
+    assert tr.t0 == am.session._t0
+    tasks = {s["task"]: s for s in tr.spans if s["cat"] == TASK}
+    prog = [s for s in tr.spans if s["cat"] not in (TASK, "park")]
+    by_id = {s["id"]: s for s in prog}
+    outer = {s["parent"][0]: s for s in prog if s["name"] == "outer"}
+    assert set(outer) == set(tasks)
+    for name, s in outer.items():
+        assert s["parent"] == (name, 1)
+        assert tasks[name]["t0"] <= s["t0"] <= s["t1"] <= tasks[name]["t1"]
+    for s in prog:
+        if s["name"] == "inner":
+            assert by_id[s["parent"]]["name"] == "outer"
+    settle = [s for s in prog if s["cat"] == "runtime"
+              and s["name"] == "settle"]
+    assert sorted(s["parent"][0] for s in settle) == sorted(tasks)
+    launch = {s["task"]: s for s in prog if s["cat"] == "runtime"
+              and s["name"] == "launch"}
+    # the first stage has neither a dependency nor an input: no launch
+    assert set(launch) == {"p.s1.t0", "p.s1.t1"}
+    ready = max(tasks[f"p.s0.t{j}"]["t1"] for j in range(2))
+    for name, s in launch.items():
+        assert s["parent"] == (name, 1)
+        assert s["t0"] == ready
+        assert s["t1"] <= outer[name]["t0"]
+
+
+def test_launch_of_a_channel_consumer_starts_at_the_put():
+    from repro.core import AppManager, Channel, Kernel, PipelineSpec, \
+        Stage, TaskSpec
+    ch = Channel("obs.data")
+    k = Kernel("synthetic.echo")
+    prod = PipelineSpec([Stage([TaskSpec(k, name="prod")], name="p",
+                               outputs=[ch])], name="P")
+    cons = PipelineSpec([Stage([TaskSpec(k, name="cons")], name="c",
+                               inputs={"x": ch})], name="C")
+    tr = Tracer()
+    prof = AppManager(PilotRuntime(slots=2, mode="real", tracer=tr)).run(
+        [prod, cons])
+    assert prof.n_failed == 0
+    tasks = {s["task"]: s for s in tr.spans if s["cat"] == TASK}
+    launch = [s for s in tr.spans if s["cat"] == "runtime"
+              and s["name"] == "launch"]
+    assert [s["task"] for s in launch] == ["cons"]
+    # the put follows the producer's settling, on the same clock
+    assert tasks["prod"]["t1"] <= launch[0]["t0"] <= launch[0]["t1"]
+    assert launch[0]["t1"] <= tasks["cons"]["t1"]
+
+
+def test_compile_listener_records_compiles_in_a_real_drain(monkeypatch):
+    """A fresh jax.jit inside a task yields compile spans parented to the
+    span open on the compiling thread, and counts compiles; the listener
+    is registered for the drain alone."""
+    import jax
+    from repro.core import AppManager
+    calls = []
+    reg = jax.monitoring.register_event_time_span_listener
+    unreg = jax.monitoring.unregister_event_time_span_listener
+    monkeypatch.setattr(jax.monitoring, "register_event_time_span_listener",
+                        lambda cb: (calls.append("reg"), reg(cb)))
+    monkeypatch.setattr(jax.monitoring,
+                        "unregister_event_time_span_listener",
+                        lambda cb: (calls.append("unreg"), unreg(cb)))
+    tr = Tracer()
+    prof = AppManager(PilotRuntime(slots=2, mode="real", tracer=tr)).run(
+        _span_pipes(stages=1))
+    assert prof.n_failed == 0
+    assert calls == ["reg", "unreg"]
+    comp = [s for s in tr.spans if s["cat"] == "compile"]
+    names = {s["name"] for s in comp}
+    assert {"jaxpr_trace", "backend_compile"} <= names
+    assert tr.metrics.counters["compiles"] >= 1
+    assert tr.metrics.counters["compiles"] == sum(
+        1 for s in comp if s["name"] == "backend_compile")
+    inner = {s["id"] for s in tr.spans if s.get("name") == "inner"}
+    assert all(s["parent"] in inner for s in comp)
+    assert all(0.0 <= s["t0"] <= s["t1"] for s in comp)
+    # after the drain, JAX's events no longer reach the tracer
+    n = len(tr.spans)
+    jax.monitoring.record_event_time_span(
+        "/jax/core/compile/backend_compile_duration", 0.0, 1.0,
+        fun_name="late")
+    assert len(tr.spans) == n
+
+
+def test_untraced_drain_records_nothing_and_registers_no_listener(
+        monkeypatch):
+    import jax
+    from repro.core import AppManager
+    calls = []
+    monkeypatch.setattr(jax.monitoring, "register_event_time_span_listener",
+                        lambda cb: calls.append(cb))
+    am = AppManager(PilotRuntime(slots=2, mode="real"))
+    prof = am.run(_span_pipes())
+    assert prof.n_failed == 0 and calls == []
+    assert "trace" not in prof.results
+    assert not any("t_settled" in t.meta or "t_input" in t.meta
+                   for t in am.session.graph.tasks.values())
+
+
+def test_decompose_totals_ignore_program_spans():
+    """The TTC decomposition reads task and park spans only: program
+    spans, even ones reaching outside the attempts, leave it as it is."""
+    from repro.obs.report import segment_from_tracer
+    tr = Tracer()
+    g = _random_dag([[0], [0, 1], [1], [], [2, 3]],
+                    [1.0, 2.0, 0.5, 3.0, 1.5, 2.5])
+    PilotRuntime(slots=2, mode="sim", tracer=tr).run(g)
+    before = decompose(segment_from_tracer(tr))
+    tr.record("compile", "backend_compile", -5.0, 1.0, fun_name="f")
+    tr.record("runtime", "launch", -1.0, 0.5, task="t001", attempt=1)
+    tr.record("runtime", "settle", 100.0, 101.0)
+    with tr.span("engine", "decode", slots=1):
+        with tr.span("engine", "wait"):
+            pass
+    tr.record("engine", "request", 0.0, 200.0, rid=3, first_token_at=1.0)
+    after = decompose(segment_from_tracer(tr))
+    assert after["totals"] == before["totals"]
+    assert after["slots"] == before["slots"]
+
+
+def test_member_spans_and_losses_do_not_depend_on_the_tracer():
+    """lm.train under a tracer: one member.train per task, parented to
+    its attempt, with a member.data child per step and a member.wait
+    child per blocking read-back (three) — and the same losses as the
+    untraced run of the same member."""
+    from repro.core import AppManager, Kernel, PipelineSpec, Stage, TaskSpec
+    from repro.plugins.lm import STATE_STORE
+
+    def losses(ensemble, tracer):
+        k = Kernel("lm.train")
+        k.arguments = {"arch": "reduced:gemma2-2b", "steps": 2,
+                       "member": 0, "ensemble": ensemble, "batch": 2,
+                       "seq": 32, "seed": 3}
+        am = AppManager(PilotRuntime(slots=1, mode="real", tracer=tracer))
+        prof = am.run([PipelineSpec([Stage([TaskSpec(k, name="m0")])],
+                                    name="p")])
+        assert prof.n_failed == 0
+        STATE_STORE.pop((ensemble, 0), None)
+        return am.session.graph.tasks["m0"].result["losses"]
+
+    tr = Tracer()
+    assert losses("obs_traced", tr) == losses("obs_plain", None)
+    body = [s for s in tr.spans if s["cat"] == "member"
+            and s["name"] == "train"]
+    assert len(body) == 1 and body[0]["parent"] == ("m0", 1)
+    kids = [s["name"] for s in tr.spans if s["cat"] == "member"
+            and s["parent"] == body[0]["id"]]
+    assert sorted(kids) == ["data", "data", "wait", "wait", "wait"]

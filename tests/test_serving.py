@@ -206,3 +206,119 @@ def test_sliding_window_cfg_falls_back_to_waves():
     srv = BatchedServer(cfg, init_params(cfg, jax.random.PRNGKey(0)),
                         batch=2, prompt_len=4, max_len=8)
     assert not srv.continuous
+
+
+# ------------------------------------------------- engine program spans
+
+def _stand_ins(B, V=16):
+    """Deterministic prefill/step stand-ins: each token is the last one
+    plus one (mod V); the cache is a per-row position counter."""
+    import jax.numpy as jnp
+
+    def prefill(params, batch):
+        toks = batch["tokens"]
+        logits = jnp.eye(V)[(toks[:, -1] + 1) % V][:, None]
+        return {"logits": logits, "cache": {"tail": {
+            "n": jnp.zeros((toks.shape[0],), jnp.int32)}}}
+
+    def step(params, cache, tokens, positions):
+        logits = jnp.eye(V)[(tokens[:, 0] + 1) % V][:, None]
+        return logits, {"tail": {"n": cache["tail"]["n"] + 1}}
+    return prefill, step
+
+
+def _stand_in_server(tracer=None, B=2, new=(3, 5, 2, 4, 3)):
+    from repro.serve import BatchedServer, Request
+    prefill, step = _stand_ins(B)
+    srv = BatchedServer(_tiny_cfg(), None, batch=B, prompt_len=4,
+                        max_len=4 + max(new), prefill_fn=prefill,
+                        step_fn=step, tracer=tracer)
+    srv.submit([Request(rid=100 + i, prompt=np.arange(4) + i,
+                        max_new_tokens=n) for i, n in enumerate(new)])
+    return srv, srv.run()
+
+
+def test_engine_spans_one_decode_per_step_with_one_wait_each():
+    from repro.obs import Tracer
+    tr = Tracer()
+    srv, done = _stand_in_server(tr)
+    spans = tr.spans
+    decode = {s["id"]: s for s in spans if s["cat"] == "engine"
+              and s["name"] == "decode"}
+    assert len(decode) == srv.stats["decode_steps"]
+    waits = [s for s in spans if s["name"] == "wait"]
+    for sid in decode:
+        assert sum(1 for w in waits if w["parent"] == sid) == 1
+    admits = {s["id"]: s for s in spans if s["name"] == "admit"}
+    assert len(admits) == srv.stats["prefills"]
+    assert sum(a["joiners"] for a in admits.values()) == len(done)
+    assert all(sum(1 for w in waits if w["parent"] == a) == 1
+               for a in admits)
+    assert sorted(r for a in admits.values() for r in a["rids"]) == \
+        sorted(r.rid for r in done)
+    assert [s["name"] for s in spans if s["name"] == "build"] == ["build"]
+    # decode steps hold their own occupancy; admissions are siblings
+    assert sum(s["slots"] for s in decode.values()) == \
+        srv.stats["slot_steps"]
+    assert all(s["parent"] is None for s in decode.values())
+    req = {s["rid"]: s for s in spans if s["name"] == "request"}
+    assert set(req) == {r.rid for r in done}
+    for r in done:
+        s = req[r.rid]
+        assert (s["t0"], s["first_token_at"], s["t1"]) == \
+            (r.submitted_at, r.first_token_at, r.done_at)
+        assert s["t0"] <= s["first_token_at"] <= s["t1"] <= tr.now()
+
+
+def test_engine_tokens_and_counts_do_not_depend_on_the_tracer():
+    jax = pytest.importorskip("jax")
+    from repro.models import init_params
+    from repro.obs import Tracer
+    from repro.serve import BatchedServer, Request
+
+    cfg = _tiny_cfg()
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(1)
+    new = [3, 5, 2, 4]
+    prompts = [rng.integers(0, cfg.vocab_size, 4) for _ in new]
+
+    def serve(tracer):
+        srv = BatchedServer(cfg, params, batch=2, prompt_len=4,
+                            max_len=4 + max(new), tracer=tracer)
+        srv.submit([Request(rid=i, prompt=p, max_new_tokens=n)
+                    for i, (p, n) in enumerate(zip(prompts, new))])
+        return {r.rid: r.out_tokens for r in srv.run()}, srv.stats
+
+    assert serve(None) == serve(Tracer())
+
+
+def test_serve_decode_returns_per_request_times_on_the_tracer_clock():
+    import dataclasses
+    import time
+    from types import SimpleNamespace
+
+    from repro.obs import Tracer
+    from repro.plugins.serve import serve_decode
+    m = TrafficModel(seed=5, window_s=2.0, base_rps=3.0, peak_rps=3.0,
+                     burst_prob=0.0, prompt_tokens=4,
+                     latency_new_tokens=2, throughput_new_tokens=3)
+    k = next(k for k in range(50) if m.requests(k, None))
+    args = {"model": dataclasses.asdict(m), "window": k, "sla": None,
+            "prompt_len": 4, "decode_slots": 2}
+    tr = Tracer()
+    out = serve_decode(args, {"runtime": SimpleNamespace(tracer=tr)})
+    want = sorted(r.rid for r in m.requests(k, None))
+    assert sorted(e["rid"] for e in out["requests"]) == want
+    req = {s["rid"]: s for s in tr.spans if s["name"] == "request"}
+    for e in out["requests"]:
+        assert e["first_token_at"] == req[e["rid"]]["first_token_at"]
+        assert e["done_at"] == req[e["rid"]]["t1"]
+        assert 0.0 <= e["first_token_at"] <= e["done_at"] <= tr.now()
+    # untraced: the same requests, timed on time.perf_counter
+    t0 = time.perf_counter()
+    plain = serve_decode(args, {})
+    assert [e["rid"] for e in plain["requests"]] == \
+        [e["rid"] for e in out["requests"]]
+    assert all(t0 <= e["first_token_at"] <= e["done_at"]
+               <= time.perf_counter() for e in plain["requests"])
+    assert plain["stats"] == out["stats"]
