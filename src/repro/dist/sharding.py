@@ -257,10 +257,10 @@ def batch_shardings(cfg: ModelConfig, mesh, specs, kind: str = "train"):
 def cache_shardings(cfg: ModelConfig, mesh, specs):
     """NamedShardings for a decode-cache pytree (``repro.serve.cache_specs``).
 
-    kv caches shard batch over the data axes and kv-heads over ``model``
-    (falling back to head_dim when num_kv_heads is indivisible — GQA
-    configs have few kv heads); recurrent/SSM states shard batch and the
-    channel dim.  ``pos`` rings are replicated.
+    kv caches, (B, S, KH*Dh) with the kv heads side by side, shard batch
+    over the data axes and that lane axis over ``model``; recurrent/SSM
+    states shard batch and the channel dim.  ``pos`` rings are
+    replicated.
     """
     sizes = mesh_axis_sizes(mesh)
 
@@ -269,8 +269,8 @@ def cache_shardings(cfg: ModelConfig, mesh, specs):
         leaf = names[-1]
         shape = tuple(x.shape)
         nd = len(shape)
-        if leaf in ("k", "v", "xk", "xv") and nd >= 4:
-            batch_dim, tp_dims = nd - 4, [nd - 2, nd - 1]
+        if leaf in ("k", "v", "xk", "xv") and nd >= 3:
+            batch_dim, tp_dims = nd - 3, [nd - 1]           # (B, S, KH*Dh)
         elif leaf == "h" and cfg.ssm_state and nd >= 3:
             batch_dim, tp_dims = nd - 3, [nd - 2, nd - 1]   # (B, d_inner, n)
         elif leaf == "h" and not cfg.ssm_state and nd >= 2:

@@ -38,10 +38,17 @@ def peaks(device_kind: str) -> HardwareSpec:
     return PEAKS[device_kind]
 
 
+def _auto(axes):
+    """Auto axis types: the program places arrays with ``in_shardings``
+    and ``with_sharding_constraint`` and lets the partitioner propagate,
+    which ``jax.make_mesh``'s default Explicit axes refuse."""
+    return (jax.sharding.AxisType.Auto,) * len(axes)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(axes))
 
 
 def make_host_mesh(shape=None, axes=("data", "model")):
@@ -49,4 +56,4 @@ def make_host_mesh(shape=None, axes=("data", "model")):
     n = len(jax.devices())
     if shape is None:
         shape = (1, n)
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(axes))

@@ -18,6 +18,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
+from repro.dist.sharding import cache_shardings
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.mamba.ops import selective_scan, selective_step
 from repro.kernels.moe_gmm.ops import gmm
@@ -201,41 +202,88 @@ def apply_attn(cfg: ModelConfig, p: Params, x, *, kind: str, positions,
 
 # -- decode (single new token against a cache) ------------------------------
 
-def _decode_attention(cfg: ModelConfig, q, kc, vc, mask):
-    """q: (B,1,H,D); kc/vc: (B,Sc,KH,D); mask: broadcastable to (B,1,Sc)."""
+def _decode_attention(cfg: ModelConfig, q, kc, vc, mask, k_new=None,
+                      v_new=None):
+    """q: (B,1,H,Dh); kc/vc: (B,Sc,KH*Dh), each position's kv heads side
+    by side (the decode cache's format); mask: broadcastable to (B,Sc).
+    ``k_new``/``v_new`` (B,1,KH*Dh), when given, are the new token's own
+    k/v, attended as one more key column beside the cache (one softmax
+    over both), so the cache need not hold them yet.
+
+    The cache is read in its own layout, never split into heads: each
+    head's scaled query sits in its kv head's lanes of a block-diagonal
+    (KH*Dh, H) query, so both contractions run over the whole lane axis
+    (KH times the operations of a per-head product, against a cache read
+    that costs the same either way).  Splitting the lane axis into heads
+    would relay out every layer's cache on the device."""
     B, _, H, Dh = q.shape
-    KH = kc.shape[2]
+    Sc, F = kc.shape[1], kc.shape[2]
+    KH = F // Dh
     G = H // KH
+    f32 = jnp.float32
     scale = cfg.attn_scale or Dh ** -0.5
-    qf = q.astype(jnp.float32).reshape(B, KH, G, Dh) * scale
-    s = jnp.einsum("bhgd,bshd->bhgs", qf, kc.astype(jnp.float32))
+    sel = jnp.repeat(jnp.eye(KH, dtype=f32), Dh, axis=0)         # (F, KH)
+    qf = q.astype(f32).reshape(B, KH, G, Dh) * scale
+    qb = (jnp.swapaxes(qf, 2, 3).reshape(B, 1, F, G)
+          * sel.T[None, :, :, None])                              # (B,KH,F,G)
+    s = jnp.einsum("bsf,bkfg->bkgs", kc.astype(f32), qb)
+    mask = jnp.broadcast_to(mask, (B, Sc))
+    if k_new is not None:
+        s = jnp.concatenate([s, jnp.einsum(
+            "bsf,bkfg->bkgs", k_new.astype(f32), qb)], axis=-1)
+        mask = jnp.concatenate([mask, jnp.ones((B, 1), bool)], axis=-1)
     if cfg.attn_softcap:
         s = jnp.tanh(s / cfg.attn_softcap) * cfg.attn_softcap
     s = jnp.where(mask[:, None, None, :], s, -1e30)
     m = jnp.max(s, axis=-1, keepdims=True)
     e = jnp.exp(s - m)
     pden = jnp.sum(e, axis=-1, keepdims=True)
-    o = jnp.einsum("bhgs,bshd->bhgd", e / jnp.maximum(pden, 1e-30),
-                   vc.astype(jnp.float32))
+    pr = e / jnp.maximum(pden, 1e-30)
+    o = jnp.einsum("bkgs,bsf->bkgf", pr[..., :Sc], vc.astype(f32))
+    if v_new is not None:
+        o = o + jnp.einsum("bkgs,bsf->bkgf", pr[..., Sc:],
+                           v_new.astype(f32))
+    # each head keeps the lanes of its own kv head
+    o = jnp.einsum("bkgjd,jk->bkgd", o.reshape(B, KH, G, KH, Dh),
+                   jnp.eye(KH, dtype=f32))
     return o.reshape(B, 1, H * Dh).astype(q.dtype)
+
+
+def flat_heads(x):
+    """(..., KH, Dh) -> (..., KH*Dh): k/v in the decode cache's format."""
+    return x.reshape(x.shape[:-2] + (x.shape[-2] * x.shape[-1],))
+
+
+def ring_cache(cfg: ModelConfig, kind: str) -> bool:
+    """Whether a layer of ``kind`` decodes against a sliding-window ring
+    cache (batch-synchronized, rewritten whole each step) rather than a
+    full-length cache that takes one new k/v row per batch row."""
+    return kind == "local" and bool(cfg.sliding_window)
 
 
 def attn_decode(cfg: ModelConfig, p: Params, x, cache: Params, positions,
                 *, kind: str, mesh=None) -> Tuple[jax.Array, Params]:
     """x: (B,1,D); positions: (B,) — PER-ROW cache positions: each batch
-    row writes its k/v at its own offset and attends under its own causal
-    mask, so a continuous-batching server can admit requests into a live
-    decode wave at unequal sequence offsets.  Sliding-window local layers
-    remain batch-synchronized (positions[0]): their ring cache carries one
-    shared ``pos`` vector with no batch dimension.  When all rows share a
-    position the per-row path is numerically identical to the old
-    synchronized one.  Returns (out (B,1,D), updated cache)."""
-    B = x.shape[0]
+    row attends under its own causal mask, so a continuous-batching
+    server can admit requests into a live decode wave at unequal
+    sequence offsets.  Caches hold each position's kv heads side by side,
+    (B, S, KH*Dh) (``flat_heads``).
+
+    Full-length caches (global layers, local layers without a window) are
+    only read here: the row attends to its cache below ``positions`` plus
+    its own new k/v, and the new rows ``{"k", "v"}`` (B,1,KH*Dh) are
+    returned for the caller to write at ``positions`` — ``decode_step``
+    writes every layer's rows at once into the (donated) stacked cache,
+    in place.  Sliding-window ring caches (``ring_cache``) stay
+    batch-synchronized (positions[0]): they carry one shared ``pos``
+    vector with no batch dimension, and the updated ring is returned
+    whole.  Returns (out (B,1,D), new rows or updated ring cache)."""
     q, k, v = _qkv(cfg, p, x, positions[:, None], kind)
+    k, v = flat_heads(k), flat_heads(v)
 
     if kind == "cross":
         raise ValueError("use attn_decode_cross")
-    if kind == "local" and cfg.sliding_window:
+    if ring_cache(cfg, kind):
         pos = positions[0]               # ring cache: batch-synchronized
         W = cache["k"].shape[1]
         slot = pos % W
@@ -244,23 +292,49 @@ def attn_decode(cfg: ModelConfig, p: Params, x, cache: Params, positions,
         pc = lax.dynamic_update_slice_in_dim(
             cache["pos"], pos[None].astype(jnp.int32), slot, axis=0)
         mask = (pc <= pos) & (pc > pos - W) & (pc >= 0)
-        mask = jnp.broadcast_to(mask[None, :], (B, W))
-        out = _decode_attention(cfg, q, kc, vc, mask)
-        new_cache = {"k": kc, "v": vc, "pos": pc}
-    else:
-        write = jax.vmap(lambda c, u, pp:
-                         lax.dynamic_update_slice_in_dim(c, u, pp, axis=0))
-        kc = write(cache["k"], k, positions)
-        vc = write(cache["v"], v, positions)
-        S = kc.shape[1]
-        mask = jnp.arange(S)[None, :] <= positions[:, None]
-        out = _decode_attention(cfg, q, kc, vc, mask)
-        new_cache = {"k": kc, "v": vc}
-    return out @ cast(cfg, p["wo"]), new_cache
+        out = _decode_attention(cfg, q, kc, vc, mask[None, :])
+        return out @ cast(cfg, p["wo"]), {"k": kc, "v": vc, "pos": pc}
+    S = cache["k"].shape[1]
+    mask = jnp.arange(S)[None, :] < positions[:, None]
+    out = _decode_attention(cfg, q, cache["k"], cache["v"], mask, k, v)
+    return out @ cast(cfg, p["wo"]), {"k": k, "v": v}
+
+
+def write_rows(cfg: ModelConfig, c, rows, positions, *, axis: int,
+               mesh=None):
+    """Write each batch row's new k/v ``rows`` (.., B, 1, KH*Dh) into the
+    cache ``c`` (.., B, S, KH*Dh) at that row's ``positions`` (B,);
+    ``axis`` is the batch axis (1 for the scanned ``blocks`` stack, 0 for
+    a ``tail`` layer).  One ``dynamic_update_slice`` per row, which XLA
+    does in place on a donated cache, touching only the row: the row is
+    contiguous on the lane axis.  (A batched scatter is lowered through a
+    relayout of the whole cache; a cache with heads and head_dim as its
+    two minor axes is laid out with positions on the lanes, where one
+    row's update costs about a pass over the cache.)
+    On a ``mesh`` each device writes its own rows into its own shard
+    (``dist.sharding.cache_shardings``; the sequence axis is never
+    sharded).  A position past the end is clamped (only an empty slot
+    sits there)."""
+    def local(cl, rl, pl):
+        for b in range(cl.shape[axis]):
+            start = [0] * cl.ndim
+            start[axis], start[axis + 1] = b, pl[b]
+            cl = lax.dynamic_update_slice(
+                cl, lax.slice_in_dim(rl, b, b + 1, axis=axis), start)
+        return cl
+
+    if mesh is None:
+        return local(c, rows, positions)
+    spec = cache_shardings(cfg, mesh, {"k": jax.ShapeDtypeStruct(
+        c.shape, c.dtype)})["k"].spec
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(spec, spec, P(spec[axis])),
+                         out_specs=spec, check_vma=False)(c, rows, positions)
 
 
 def attn_decode_cross(cfg: ModelConfig, p: Params, x, cache: Params):
-    """Cross-attention decode: kv precomputed at prefill (static)."""
+    """Cross-attention decode: kv precomputed at prefill (static), in the
+    cache's (B, Sm, KH*Dh) format."""
     B = x.shape[0]
     q = (x @ cast(cfg, p["wq"])).reshape(B, 1, cfg.num_heads, cfg.head_dim)
     Sm = cache["xk"].shape[1]

@@ -177,21 +177,21 @@ def _attn_with_cache(cfg, p, x, *, kind, positions, seg_ids, mesh, cache_len):
                         scale=cfg.attn_scale or None,
                         seg_q=seg_ids, seg_kv=seg_ids, impl=L._impl(cfg))
     out = o.reshape(B, S, cfg.q_dim) @ L.cast(cfg, p["wo"])
-    if kind == "local" and cfg.sliding_window:
+    if L.ring_cache(cfg, kind):
         W = cfg.sliding_window
         take = min(W, S)
         ks, vs = k[:, -take:], v[:, -take:]
         pos_tail = jnp.arange(S - take, S, dtype=jnp.int32)
         slots = pos_tail % W
-        kc = jnp.zeros((B, W) + k.shape[2:], k.dtype).at[:, slots].set(ks)
-        vc = jnp.zeros((B, W) + v.shape[2:], v.dtype).at[:, slots].set(vs)
+        ks, vs = L.flat_heads(ks), L.flat_heads(vs)
+        kc = jnp.zeros((B, W, ks.shape[-1]), k.dtype).at[:, slots].set(ks)
+        vc = jnp.zeros((B, W, vs.shape[-1]), v.dtype).at[:, slots].set(vs)
         pc = jnp.full((W,), -1, jnp.int32).at[slots].set(pos_tail)
         cache = {"k": kc, "v": vc, "pos": pc}
     else:
-        pad = cache_len - S
-        kc = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        vc = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        cache = {"k": kc, "v": vc}
+        pad = ((0, 0), (0, cache_len - S), (0, 0))
+        cache = {"k": jnp.pad(L.flat_heads(k), pad),
+                 "v": jnp.pad(L.flat_heads(v), pad)}
     return out, cache
 
 
@@ -207,19 +207,21 @@ def _cross_with_cache(cfg, p, x, mem):
     o = flash_attention(q, k, v, causal=False, softcap=cfg.attn_softcap,
                         scale=cfg.attn_scale or None, impl=L._impl(cfg))
     out = o.reshape(B, S, cfg.q_dim) @ L.cast(cfg, p["wo"])
-    return out, {"xk": k, "xv": v}
+    return out, {"xk": L.flat_heads(k), "xv": L.flat_heads(v)}
 
 
 def decode_block(cfg: ModelConfig, bp: Params, h, cache: Params, kind: str,
                  *, positions, mesh):
-    """Single-token step.  h: (B,1,D).  Returns (h, new_cache)."""
-    new_cache = dict(cache)
+    """Single-token step.  h: (B,1,D).  Returns (h, upd): ``upd`` holds
+    only the cache entries the step changes — the new k/v rows of a
+    full-length attention cache (``decode_step`` writes them), or the
+    whole new ring / recurrent state; cross-attention ``xk``/``xv`` are
+    read, never returned."""
     if kind in ("global", "local"):
         xin = L.apply_norm(cfg, bp["ln1"], h)
         sub = {k: cache[k] for k in ("k", "v", "pos") if k in cache}
         a, upd = L.attn_decode(cfg, bp["attn"], xin, sub, positions,
                                kind=kind, mesh=mesh)
-        new_cache.update(upd)
         if cfg.post_norms:
             a = L.apply_norm(cfg, bp["ln1_post"], a)
         h = h + a
@@ -240,18 +242,16 @@ def decode_block(cfg: ModelConfig, bp: Params, h, cache: Params, kind: str,
         xin = L.apply_norm(cfg, bp["ln1"], h)
         m, upd = L.rglru_decode(cfg, bp["rec"], xin,
                                 {"h": cache["h"], "conv": cache["conv"]})
-        new_cache.update(upd)
         h = h + m
         h = h + L.apply_mlp(cfg, bp["mlp"], L.apply_norm(cfg, bp["ln2"], h))
     elif kind == "mamba":
         xin = L.apply_norm(cfg, bp["ln1"], h)
         m, upd = L.mamba_decode(cfg, bp["mamba"], xin,
                                 {"h": cache["h"], "conv": cache["conv"]})
-        new_cache.update(upd)
         h = h + m
     else:
         raise ValueError(kind)
-    return h, new_cache
+    return h, upd
 
 
 # ---------------------------------------------------------------- embed/head
@@ -385,14 +385,35 @@ def forward(cfg: ModelConfig, params: Params, tokens, *, positions=None,
 
 # ---------------------------------------------------------------- decode
 
+def _apply_updates(cfg: ModelConfig, kind: str, cache: Params, upd: Params,
+                   positions, *, axis: int, mesh) -> Params:
+    """One layer's (or scanned sub-layer's) new cache: ``decode_block``'s
+    new k/v rows written at ``positions`` into a full-length attention
+    cache, or its whole new state put in place; entries it did not return
+    (cross-attention ``xk``/``xv``) pass through."""
+    new = dict(cache)
+    if kind in ("global", "local") and not L.ring_cache(cfg, kind):
+        for name in ("k", "v"):
+            new[name] = L.write_rows(cfg, cache[name], upd[name],
+                                     positions, axis=axis, mesh=mesh)
+    else:
+        new.update(upd)
+    return new
+
+
 def decode_step(cfg: ModelConfig, params: Params, cache: Params, tokens,
                 positions, *, mesh=None):
     """One token for the whole batch.  tokens: (B,1); positions: (B,) —
     per-row offsets: rows may sit at different sequence positions (see
     layers.attn_decode), which is what lets the continuous-batching server
     admit a freshly prefilled request into a running decode wave.
+
+    The layer scan only reads full-length attention caches and emits each
+    layer's new k/v rows; one write per leaf after the scan puts them at
+    ``positions`` into the stacked cache, which XLA updates in place when
+    the caller donates ``cache`` (``BatchedServer`` does).  Sliding-window
+    rings and recurrent states are small and re-emitted whole by the scan.
     Returns (logits (B,1,V), new cache)."""
-    B = tokens.shape[0]
     h = embed_tokens(cfg, params, tokens, positions[:, None])
     h = constrain_batch(cfg, mesh, h, "serve")
     period, G, n_tail = _layout(cfg)
@@ -402,27 +423,31 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Params, tokens,
         def body(carry, xs):
             hh = carry
             bp, cg = xs
-            ncg = {}
+            ug = {}
             for s in range(period):
                 kind = cfg.layer_pattern[s]
-                hh, nc = decode_block(cfg, bp[f"sub_{s}"], hh, cg[f"sub_{s}"],
-                                      kind, positions=positions, mesh=mesh)
-                ncg[f"sub_{s}"] = nc
+                hh, ug[f"sub_{s}"] = decode_block(
+                    cfg, bp[f"sub_{s}"], hh, cg[f"sub_{s}"], kind,
+                    positions=positions, mesh=mesh)
             hh = constrain_batch(cfg, mesh, hh, "serve")
-            return hh, ncg
+            return hh, ug
 
-        h, nbc = lax.scan(body, h, (params["blocks"], cache["blocks"]))
-        new_cache["blocks"] = nbc
+        h, ubc = lax.scan(body, h, (params["blocks"], cache["blocks"]))
+        new_cache["blocks"] = {
+            f"sub_{s}": _apply_updates(
+                cfg, cfg.layer_pattern[s], cache["blocks"][f"sub_{s}"],
+                ubc[f"sub_{s}"], positions, axis=1, mesh=mesh)
+            for s in range(period)}
 
     if n_tail:
         nt = {}
         for j in range(n_tail):
-            i = G * period + j
-            kind = cfg.layer_kind(i)
-            h, nc = decode_block(cfg, params["tail"][f"block_{j}"], h,
-                                 cache["tail"][f"block_{j}"], kind,
-                                 positions=positions, mesh=mesh)
-            nt[f"block_{j}"] = nc
+            kind = cfg.layer_kind(G * period + j)
+            c = cache["tail"][f"block_{j}"]
+            h, upd = decode_block(cfg, params["tail"][f"block_{j}"], h, c,
+                                  kind, positions=positions, mesh=mesh)
+            nt[f"block_{j}"] = _apply_updates(cfg, kind, c, upd, positions,
+                                              axis=0, mesh=mesh)
         new_cache["tail"] = nt
 
     h = L.apply_norm(cfg, params["final_norm"], h)
@@ -435,19 +460,19 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Params, tokens,
 def _block_cache_zeros(cfg: ModelConfig, kind: str, B: int, cache_len: int,
                        cross: bool):
     dt = L._dt(cfg)
-    KH, Dh = cfg.num_kv_heads, cfg.head_dim
+    F = cfg.num_kv_heads * cfg.head_dim      # kv heads side by side
     if kind in ("global", "local"):
-        if kind == "local" and cfg.sliding_window:
+        if L.ring_cache(cfg, kind):
             W = min(cfg.sliding_window, cache_len)
-            c = {"k": jnp.zeros((B, W, KH, Dh), dt),
-                 "v": jnp.zeros((B, W, KH, Dh), dt),
+            c = {"k": jnp.zeros((B, W, F), dt),
+                 "v": jnp.zeros((B, W, F), dt),
                  "pos": jnp.full((W,), -1, jnp.int32)}
         else:
-            c = {"k": jnp.zeros((B, cache_len, KH, Dh), dt),
-                 "v": jnp.zeros((B, cache_len, KH, Dh), dt)}
+            c = {"k": jnp.zeros((B, cache_len, F), dt),
+                 "v": jnp.zeros((B, cache_len, F), dt)}
         if cross:
-            c["xk"] = jnp.zeros((B, cfg.encoder_seq, KH, Dh), dt)
-            c["xv"] = jnp.zeros((B, cfg.encoder_seq, KH, Dh), dt)
+            c["xk"] = jnp.zeros((B, cfg.encoder_seq, F), dt)
+            c["xv"] = jnp.zeros((B, cfg.encoder_seq, F), dt)
         return c
     if kind == "rec":
         W = cfg.lru_width_

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import decode_step, forward, init_cache, lm_logits
+from repro.models.layers import ring_cache
 
 
 def serving_config(cfg: ModelConfig) -> ModelConfig:
@@ -74,6 +75,15 @@ def _merge_rows(old, new, mask, *, axis):
     return jax.tree_util.tree_map(sel, old, new)
 
 
+def _deleted(tree) -> bool:
+    """Whether every leaf of ``tree`` is a deleted ``jax.Array``: a cache
+    that a step took as a donated argument, whose buffers the step's new
+    cache now holds."""
+    leaves = jax.tree_util.tree_leaves(tree)
+    return bool(leaves) and all(
+        isinstance(x, jax.Array) and x.is_deleted() for x in leaves)
+
+
 class BatchedServer:
     """Host-side continuous-batching server over fixed decode slots.
 
@@ -86,7 +96,11 @@ class BatchedServer:
     ``models.layers.attn_decode``; sliding-window local layers keep a
     batch-synchronized ring cache (one position vector, no batch dim), so
     configs containing them fall back to the legacy synchronized-wave loop
-    (evict-at-own-length still holds; no mid-wave admission).
+    (evict-at-own-length still holds; no mid-wave admission).  The jitted
+    decode step, on one device or a mesh, takes its cache as a donated
+    argument: each step rebinds ``cache`` to the step's output, and the
+    step writes the new k/v rows into the old buffers in place
+    (``models.decode_step``).
 
     ``clock`` stamps Request.submitted_at/first_token_at/done_at: the
     tracer's clock when a ``tracer`` (repro.obs.Tracer) is given, else
@@ -98,8 +112,10 @@ class BatchedServer:
     ``engine.build`` (this constructor), ``engine.admit`` (an admission
     with joiners: prefill dispatch, row merge, first-token read-back),
     ``engine.decode`` (one decode step after admission, through its
-    bookkeeping), ``engine.wait`` (each blocking read-back, inside those
-    two) and ``engine.request`` (submit to eviction, per ``rid``).
+    bookkeeping; ``cache_donated``: whether the cache passed in was
+    consumed, i.e. every leaf deleted), ``engine.wait`` (each blocking
+    read-back, inside those two) and ``engine.request`` (submit to
+    eviction, per ``rid``).
     """
 
     def __init__(self, cfg: ModelConfig, params, *, batch: int, prompt_len: int,
@@ -114,13 +130,13 @@ class BatchedServer:
             clock = time.perf_counter if tracer is None else tracer.now
         self.clock = clock
         # sliding-window ring caches are batch-synchronized -> wave mode
-        self.continuous = not (cfg.sliding_window and any(
-            cfg.layer_kind(i) == "local" for i in range(cfg.num_layers)))
+        self.continuous = not any(ring_cache(cfg, cfg.layer_kind(i))
+                                  for i in range(cfg.num_layers))
         if prefill_fn is not None or step_fn is not None:
             self.prefill, self.step = prefill_fn, step_fn
         elif mesh is not None:
             # pin the distributed layout: params/cache stay sharded across
-            # decode steps (cache donated), logits replicated for sampling
+            # decode steps, logits replicated for sampling
             from repro.dist.sharding import cache_shardings, state_shardings
             p_sh = state_shardings(cfg, mesh, params)
             c_sh = cache_shardings(cfg, mesh,
@@ -136,7 +152,8 @@ class BatchedServer:
         else:
             self.prefill = jax.jit(
                 build_prefill_step(cfg, mesh, cache_len=max_len))
-            self.step = jax.jit(build_serve_step(cfg, mesh))
+            self.step = jax.jit(build_serve_step(cfg, mesh),
+                                donate_argnums=(1,))
         self.queue: collections.deque = collections.deque()
         self.stats = {"served": 0, "decode_steps": 0, "prefills": 0,
                       "slot_steps": 0}
@@ -220,6 +237,7 @@ class BatchedServer:
                 step = tr.open("engine", "decode", slots=sum(
                     s is not None for s in slots))
                 evicted = len(done)
+                passed = cache
             logits, cache = self.step(
                 self.params, cache, jnp.asarray(last, jnp.int32)[:, None],
                 jnp.asarray(positions, jnp.int32))
@@ -243,7 +261,7 @@ class BatchedServer:
                     self.stats["served"] += 1
                     slots[i] = None      # evict: slot free next admission
             if tr is not None:
-                tr.close(step)
+                tr.close(step, cache_donated=_deleted(passed))
                 for r in done[evicted:]:
                     tr.record("engine", "request", r.submitted_at,
                               r.done_at, rid=r.rid,

@@ -73,6 +73,46 @@ def test_prefill_decode_matches_forward(arch):
     assert max(errs) < 2e-2, (arch, errs)
 
 
+def _per_row(cfg):
+    """Every layer keeps a full-length per-row attention cache (no ring,
+    no recurrent state), so rows may sit at unequal positions."""
+    from repro.models.layers import ring_cache
+    return all(k in ("global", "local") and not ring_cache(cfg, k)
+               for k in map(cfg.layer_kind, range(cfg.num_layers)))
+
+
+PER_ROW = [a for a in ARCHS if _per_row(reduced(get_config(a)))]
+
+
+@pytest.mark.parametrize("arch,scan", [(a, True) for a in PER_ROW]
+                         + [("minicpm-2b", False)])
+def test_decode_at_unequal_row_positions_matches_forward(arch, scan):
+    """Rows decode at their own positions.  Row 1 is rewound below its
+    prefill, whose cache holds k/v of other tokens from there on: the
+    step must attend to none of them and write its own rows over them.
+    ``scan=False`` unrolls every layer (the ``tail`` cache path)."""
+    cfg = reduced(get_config(arch)).replace(scan_layers=scan)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    B, S, EXTRA, CLEN, BACK = 2, 24, 4, 48, 6
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S + EXTRA), 0,
+                                cfg.vocab_size)
+    kw = _inputs(cfg, B, S)
+    full = lm_logits(cfg, params, forward(cfg, params, tokens, **kw)["h"])
+    stale = tokens.at[1, S - BACK:S].set(
+        (tokens[1, S - BACK:S] + 1) % cfg.vocab_size)
+    cache = forward(cfg, params, stale[:, :S], cache_len=CLEN,
+                    **kw)["cache"]
+    start = jnp.asarray([S, S - BACK], jnp.int32)
+    rows = jnp.arange(B)
+    errs = []
+    for t in range(EXTRA):
+        pos = start + t
+        logits, cache = decode_step(cfg, params, cache,
+                                    tokens[rows, pos][:, None], pos)
+        errs.append(float(jnp.max(jnp.abs(logits[:, 0] - full[rows, pos]))))
+    assert max(errs) < 2e-2, (arch, errs)
+
+
 def test_moe_no_drop_matches_dense_reference():
     """With generous capacity, sorted-dispatch MoE == dense compute-all."""
     from repro.models import layers as L

@@ -175,6 +175,87 @@ def test_continuous_batching_token_identity_and_backfill():
          for i, n in enumerate(new)], 2, step_cost_s=1.0).steps
 
 
+def test_mid_wave_admission_matches_b1_and_forward_with_cache_donated():
+    """Requests admitted into a live wave decode at unequal per-row
+    positions and serve the tokens of a B=1 server and of greedy decoding
+    by the full forward pass; every step consumes (donates) the cache it
+    is given, and each traced ``engine.decode`` span says so."""
+    jax = pytest.importorskip("jax")
+    import jax.numpy as jnp
+
+    from repro.models import forward, init_params, lm_logits
+    from repro.obs import Tracer
+    from repro.serve import BatchedServer, Request
+
+    cfg = _tiny_cfg(dtype="float32")
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    rng = np.random.default_rng(3)
+    S0, new = 4, [6, 2, 5, 3, 4]
+    prompts = [rng.integers(0, cfg.vocab_size, S0) for _ in new]
+
+    def serve(batch, reqs, tracer=None):
+        srv = BatchedServer(cfg, params, batch=batch, prompt_len=S0,
+                            max_len=S0 + max(new), tracer=tracer)
+        step, passed = srv.step, []
+
+        def spy(p, cache, tokens, positions):
+            passed.append(cache)
+            return step(p, cache, tokens, positions)
+        srv.step = spy
+        srv.submit(reqs)
+        return srv, passed, {r.rid: r.out_tokens for r in srv.run()}
+
+    tr = Tracer()
+    srv, passed, got = serve(2, [Request(rid=i, prompt=p, max_new_tokens=n)
+                                 for i, (p, n) in enumerate(zip(prompts,
+                                                                new))], tr)
+    assert srv.stats["prefills"] > 1          # admissions into a live wave
+    assert len(passed) == srv.stats["decode_steps"]
+    for cache in passed:
+        assert all(x.is_deleted() for x in jax.tree_util.tree_leaves(cache))
+    flags = [s["cache_donated"] for s in tr.spans
+             if s["cat"] == "engine" and s["name"] == "decode"]
+    assert flags == [True] * srv.stats["decode_steps"]
+    for i, (p, n) in enumerate(zip(prompts, new)):
+        _, _, ref = serve(1, [Request(rid=i, prompt=p, max_new_tokens=n)])
+        assert got[i] == ref[i], f"rid {i} diverged from B=1 reference"
+        seq = [int(t) for t in p]
+        for _ in range(n + 1):                # the prefill's token first
+            h = forward(cfg, params, jnp.asarray([seq], jnp.int32))["h"]
+            seq.append(int(jnp.argmax(lm_logits(cfg, params, h)[0, -1])))
+        assert got[i] == seq[S0 + 1:], f"rid {i} diverged from forward"
+
+
+def test_mesh_server_writes_rows_per_shard_like_single_device():
+    """The sharded branch (cache pinned to ``cache_shardings``, each
+    device writing its own rows) serves what the single-device branch
+    serves, and also consumes each step's cache."""
+    jax = pytest.importorskip("jax")
+    from repro.launch.mesh import make_host_mesh
+    from repro.models import init_params
+    from repro.obs import Tracer
+    from repro.serve import BatchedServer, Request
+
+    cfg = _tiny_cfg(dtype="float32")
+    params = init_params(cfg, jax.random.PRNGKey(4))
+    rng = np.random.default_rng(4)
+    new = [3, 5, 2, 4]
+    prompts = [rng.integers(0, cfg.vocab_size, 4) for _ in new]
+
+    def serve(mesh):
+        tr = Tracer()
+        srv = BatchedServer(cfg, params, batch=2, prompt_len=4,
+                            max_len=4 + max(new), mesh=mesh, tracer=tr)
+        srv.submit([Request(rid=i, prompt=p, max_new_tokens=n)
+                    for i, (p, n) in enumerate(zip(prompts, new))])
+        got = {r.rid: r.out_tokens for r in srv.run()}
+        assert all(s["cache_donated"] for s in tr.spans
+                   if s["name"] == "decode")
+        return got
+
+    assert serve(make_host_mesh((1, 1))) == serve(None)
+
+
 def test_request_clock_stamps_and_submit_guard():
     jax = pytest.importorskip("jax")
     from repro.models import init_params
