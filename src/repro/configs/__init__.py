@@ -23,6 +23,7 @@ from repro.configs import falcon_mamba_7b    # noqa: F401
 from repro.configs import whisper_large_v3   # noqa: F401
 from repro.configs import qwen3_moe_30b_a3b  # noqa: F401
 from repro.configs import grok_1_314b        # noqa: F401
+from repro.configs import moonlight_16b_a3b  # noqa: F401
 from repro.configs import toy                # noqa: F401
 
 ALL_ARCHS = list_configs()

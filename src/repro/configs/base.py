@@ -55,6 +55,16 @@ class ModelConfig:
     rope_theta_global: float = 0.0   # if >0, separate theta for global layers
     attn_scale: float = 0.0          # 0 => 1/sqrt(head_dim)
 
+    # -- multi-head latent attention (MLA; DeepSeek-V2/V3) ---------------------
+    # kv_lora_rank > 0 selects MLA: keys and values come from one latent of
+    # this width (RMS-normed) plus one roped key shared by every head, and
+    # the decode cache holds that latent row: kv_lora_rank + qk_rope_dim
+    # lanes.  head_dim is then qk_nope_dim + qk_rope_dim (the q/k width).
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+
     # -- embeddings ----------------------------------------------------------
     tie_embeddings: bool = True
     emb_scale: bool = False          # multiply embeddings by sqrt(d_model)
@@ -63,6 +73,13 @@ class ModelConfig:
     num_experts: int = 0
     experts_per_tok: int = 0
     moe_d_ff: int = 0                # expert hidden dim (0 => use d_ff)
+    first_dense_layers: int = 0      # leading layers with the dense MLP
+    num_shared_experts: int = 0      # one SwiGLU of this many expert widths
+    router: str = "softmax"          # softmax | sigmoid_bias (noaux_tc)
+    routed_scale: float = 1.0        # routed weights' factor (sigmoid_bias)
+    moe_capacity: float = 1.25       # capacity factor; 0 => dropless
+    experts_held: int = 0            # experts this rank holds (0 => all)
+    expert_rank: int = 0             # holds [rank*held, (rank+1)*held)
 
     # -- SSM (mamba) ----------------------------------------------------------
     ssm_state: int = 0
@@ -81,6 +98,7 @@ class ModelConfig:
     vision_tokens: int = 0           # prepended patch-embedding stub tokens
 
     # -- numerics / parallelism -----------------------------------------------
+    norm_eps: float = 1e-6           # epsilon of every block norm
     dtype: str = "bfloat16"          # compute dtype
     param_dtype: str = "float32"     # master parameter dtype
     optstate_dtype: str = "float32"  # Adam m/v dtype (bf16 for the huge archs)
@@ -103,6 +121,26 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def cache_width(self) -> int:
+        """Lanes of one position of a full attention layer's decode cache:
+        the latent row for MLA, else the kv heads side by side."""
+        if self.mla:
+            return self.kv_lora_rank + self.qk_rope_dim
+        return self.kv_dim
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    def moe_layer(self, i: int) -> bool:
+        """Whether layer ``i`` has the expert MLP (not a leading dense one)."""
+        return bool(self.num_experts) and i >= self.first_dense_layers
 
     @property
     def d_inner(self) -> int:
@@ -155,15 +193,25 @@ class ModelConfig:
         if not self.tie_embeddings:
             n += self.vocab_size * d
         per_layer = {}
-        attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+        if self.mla:
+            r, H = self.kv_lora_rank, self.num_heads
+            attn = (d * self.q_dim + d * (r + self.qk_rope_dim) + r
+                    + r * H * (self.qk_nope_dim + self.v_head_dim)
+                    + H * self.v_head_dim * d)
+        else:
+            attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
         gated = self.mlp in ("swiglu", "geglu")
         def mlp_params(ff):
             return d * ff * (3 if gated else 2)
+        moe = (d * self.num_experts + (self.num_experts if self.router
+                                       == "sigmoid_bias" else 0)
+               + self.held_experts * mlp_params(self.expert_ff)
+               + mlp_params(self.num_shared_experts * self.expert_ff))
+        n += (attn + mlp_params(self.d_ff)) * self.first_dense_layers
         for kind in set(self.layer_kinds):
             if kind in ("global", "local"):
                 p = attn + (mlp_params(self.d_ff) if self.num_experts == 0
-                            else d * self.num_experts
-                            + self.num_experts * (self.expert_ff * d * (3 if gated else 2)))
+                            else moe)
             elif kind == "rec":
                 w = self.lru_width_
                 p = 2 * d * w + w * d + 3 * w * w + self.ssm_conv * w + mlp_params(self.d_ff)
@@ -174,7 +222,8 @@ class ModelConfig:
             else:
                 raise ValueError(kind)
             per_layer[kind] = p
-        n += sum(per_layer[k] for k in self.layer_kinds)
+        n += sum(per_layer[k]
+                 for k in self.layer_kinds[self.first_dense_layers:])
         if self.encoder_layers:
             # encoder layers: self-attn + mlp; decoder adds cross-attn
             n += self.encoder_layers * (attn + mlp_params(self.d_ff))
@@ -328,7 +377,15 @@ def reduced(cfg: ModelConfig, *, layers: Optional[int] = None) -> ModelConfig:
     )
     if cfg.num_experts:
         kw.update(num_experts=4, experts_per_tok=min(2, cfg.experts_per_tok),
-                  moe_d_ff=32)
+                  moe_d_ff=32,
+                  experts_held=min(cfg.experts_held, 4) if cfg.experts_held
+                  else 0,
+                  first_dense_layers=min(cfg.first_dense_layers, 1))
+        if cfg.first_dense_layers:
+            kw["num_layers"] = L + 1
+    if cfg.mla:
+        kw.update(kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8,
+                  v_head_dim=16, head_dim=24)
     if cfg.ssm_state:
         kw.update(ssm_state=4, ssm_conv=4, ssm_expand=2, dt_rank=8)
     if cfg.lru_width:

@@ -37,5 +37,5 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         from repro.kernels.flash_attention.pallas_kernel import flash_attention_pallas
         return forward_only("flash_attention", partial(
             flash_attention_pallas, interpret=(impl == "pallas_interpret"),
-            **kw), q, k, v)
+            block_q=q_chunk, block_kv=kv_chunk, **kw), q, k, v)
     raise ValueError(f"unknown attention impl {impl!r}")

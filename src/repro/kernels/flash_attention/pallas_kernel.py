@@ -82,7 +82,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         p = jnp.exp(s - m_new)
         p = jnp.where(mask, p, 0.0)
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0].astype(jnp.float32)                 # (Bkv, D)
+        v = v_ref[0].astype(jnp.float32)                 # (Bkv, Dv)
         pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         acc_ref[...] = acc_ref[...] * corr + pv
@@ -95,23 +95,32 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             .astype(o_ref.dtype)
 
 
+def _block(want: int, n: int) -> int:
+    """The largest block of at most ``want`` rows, halving, that divides
+    ``n``: few grid steps (each has a fixed cost) on any length."""
+    b = min(want, n)
+    while n % b:
+        b //= 2
+    return b
+
+
 def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
                            softcap: float = 0.0,
                            scale: Optional[float] = None, q_offset: int = 0,
                            seg_q=None, seg_kv=None,
                            block_q: int = 128, block_kv: int = 128,
                            interpret: bool = False):
-    """q: (B, Sq, H, D); k/v: (B, Sk, KH, D).  Returns (B, Sq, H, D)."""
+    """q: (B, Sq, H, D); k: (B, Sk, KH, D); v: (B, Sk, KH, Dv), Dv <= D
+    (latent attention's values are narrower than its keys).  Returns
+    (B, Sq, H, Dv)."""
     if seg_q is not None:
         raise NotImplementedError("segment ids: use the xla path")
     B, Sq, H, D = q.shape
     _, Sk, KH, _ = k.shape
+    Dv = v.shape[-1]
     G = H // KH
     scale = scale if scale is not None else D ** -0.5
-    block_q = min(block_q, Sq)
-    block_kv = min(block_kv, Sk)
-    assert Sq % block_q == 0 and Sk % block_kv == 0, \
-        "pallas path needs block-aligned sequence lengths"
+    block_q, block_kv = _block(block_q, Sq), _block(block_kv, Sk)
     nq, nkv = Sq // block_q, Sk // block_kv
 
     # (B, Sq, KH, G, D) -> (B, KH, G, Sq, D); k/v -> (B, KH, Sk, D)
@@ -120,7 +129,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
     vr = v.transpose(0, 2, 1, 3)
     qf = qr.reshape(B * KH, G, Sq, D)
     kf = kr.reshape(B * KH, Sk, D)
-    vf = vr.reshape(B * KH, Sk, D)
+    vf = vr.reshape(B * KH, Sk, Dv)
 
     kernel = functools.partial(
         _attn_kernel, scale=scale, causal=causal, window=window,
@@ -134,21 +143,21 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
         in_specs=[
             pl.BlockSpec((1, G, block_q, D), lambda b, i, j: (b, 0, i, 0)),
             pl.BlockSpec((1, block_kv, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_kv, D), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_kv, Dv), lambda b, i, j: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, G, block_q, D),
+        out_specs=pl.BlockSpec((1, G, block_q, Dv),
                                lambda b, i, j: (b, 0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * KH, G, Sq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B * KH, G, Sq, Dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((rows, 1), jnp.float32),   # m
             pltpu.VMEM((rows, 1), jnp.float32),   # l
-            pltpu.VMEM((rows, D), jnp.float32),   # acc
+            pltpu.VMEM((rows, Dv), jnp.float32),  # acc
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)
 
-    # (B*KH, G, Sq, D) -> (B, Sq, H, D)
-    return out.reshape(B, KH, G, Sq, D).transpose(0, 3, 1, 2, 4) \
-        .reshape(B, Sq, H, D)
+    # (B*KH, G, Sq, Dv) -> (B, Sq, H, Dv)
+    return out.reshape(B, KH, G, Sq, Dv).transpose(0, 3, 1, 2, 4) \
+        .reshape(B, Sq, H, Dv)

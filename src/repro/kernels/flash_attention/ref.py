@@ -3,8 +3,8 @@
 Shapes (GQA layout):
   q: (B, Sq, H, D)    with H = KH * G
   k: (B, Sk, KH, D)
-  v: (B, Sk, KH, D)
-Returns (B, Sq, H, D).
+  v: (B, Sk, KH, Dv)  (Dv may be narrower than D: latent attention)
+Returns (B, Sq, H, Dv).
 
 Masking: causal (q position i attends to kv position j <= i), optional
 sliding window (i - j < window), optional segment ids (block-diagonal
@@ -54,4 +54,4 @@ def attention_ref(q, k, v, *, causal: bool = True, window: int = 0,
     denom = jnp.sum(e, axis=-1, keepdims=True)
     p = e / jnp.maximum(denom, 1e-30)
     o = jnp.einsum("bhgqk,bkhd->bqhgd", p, vf)
-    return o.reshape(B, Sq, H, D).astype(q.dtype)
+    return o.reshape(B, Sq, H, v.shape[-1]).astype(q.dtype)

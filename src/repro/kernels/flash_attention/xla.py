@@ -24,10 +24,12 @@ def _chunk_attn(qf, ks, vs, koffs, *, causal, window, softcap, q_offset,
     """Online softmax over the stacked kv chunks ``ks``/``vs``.
 
     qf: (B, Cq, KH, G, D) fp32, pre-scaled.
-    ks/vs: (nk, B, Ck, KH, D); koffs: (nk,) chunk start positions.
-    Returns (B, Cq, KH, G, D) fp32 (unnormalized handled internally).
+    ks: (nk, B, Ck, KH, D); vs: (nk, B, Ck, KH, Dv); koffs: (nk,) chunk
+    start positions.
+    Returns (B, Cq, KH, G, Dv) fp32 (unnormalized handled internally).
     """
-    B, Cq, KH, G, D = qf.shape
+    B, Cq, KH, G, _ = qf.shape
+    Dv = vs.shape[-1]
     qf_dtype = qf_dtype or ks.dtype
     Ck = ks.shape[2]
     qpos = q_offset + qi * q_chunk + jnp.arange(Cq)
@@ -71,7 +73,7 @@ def _chunk_attn(qf, ks, vs, koffs, *, causal, window, softcap, q_offset,
 
     m0 = jnp.full((B, KH, G, Cq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, KH, G, Cq), jnp.float32)
-    a0 = jnp.zeros((B, KH, G, Cq, D), jnp.float32)
+    a0 = jnp.zeros((B, KH, G, Cq, Dv), jnp.float32)
     xs = (ks, vs, koffs) if seg_kvs is None else (ks, vs, koffs, seg_kvs)
     (m, l, acc), _ = lax.scan(step, (m0, l0, a0), xs)
     out = acc / jnp.maximum(l, 1e-30)[..., None]
@@ -82,9 +84,11 @@ def attention_xla(q, k, v, *, causal: bool = True, window: int = 0,
                   softcap: float = 0.0, scale: Optional[float] = None,
                   q_offset: int = 0, seg_q=None, seg_kv=None,
                   q_chunk: int = 512, kv_chunk: int = 512):
-    """Chunked attention.  Layout: q (B,Sq,H,D), k/v (B,Sk,KH,D)."""
+    """Chunked attention.  Layout: q (B,Sq,H,D), k (B,Sk,KH,D), v
+    (B,Sk,KH,Dv); returns (B,Sq,H,Dv)."""
     B, Sq, H, D = q.shape
     _, Sk, KH, _ = k.shape
+    Dv = v.shape[-1]
     G = H // KH
     scale = scale if scale is not None else D ** -0.5
 
@@ -98,7 +102,7 @@ def attention_xla(q, k, v, *, causal: bool = True, window: int = 0,
 
     nq, nk = Sq // q_chunk, Sk // kv_chunk
     k_ch = k.reshape(B, nk, kv_chunk, KH, D).transpose(1, 0, 2, 3, 4)
-    v_ch = v.reshape(B, nk, kv_chunk, KH, D).transpose(1, 0, 2, 3, 4)
+    v_ch = v.reshape(B, nk, kv_chunk, KH, Dv).transpose(1, 0, 2, 3, 4)
     seg_kv_ch = (seg_kv.reshape(B, nk, kv_chunk).transpose(1, 0, 2)
                  if seg_kv is not None else None)
     koffs = jnp.arange(nk) * kv_chunk
@@ -126,5 +130,5 @@ def attention_xla(q, k, v, *, causal: bool = True, window: int = 0,
             static_argnums=())
         o = attn_fn(qf, k_ch[lo:hi], v_ch[lo:hi], koffs[lo:hi], sq,
                     seg_kv_ch[lo:hi] if seg_kv_ch is not None else None)
-        outs.append(o.reshape(B, q_chunk, H, D))
+        outs.append(o.reshape(B, q_chunk, H, Dv))
     return jnp.concatenate(outs, axis=1).astype(q.dtype)

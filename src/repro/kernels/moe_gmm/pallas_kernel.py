@@ -4,7 +4,12 @@ Design: grid (E, C/block_c, F/block_f); ``group_sizes`` arrives via scalar
 prefetch (SMEM) and row-blocks entirely past an expert's token count skip
 their MXU work via ``pl.when`` — this recovers the padding FLOPs the plain
 batched-matmul XLA path wastes at low expert load (the kernel-level win this
-module exists for).
+module exists for).  Their inputs are not fetched either: a dead block's
+index maps point at the blocks the expert's last live step read (its last
+live row block, the last F block of the weight), which the pipeline
+already holds, so a layout whose capacity is far above the rows routed
+(a dropless expert layer holds every token's row) reads each expert's
+weight once per live row block, and an expert with no rows not at all.
 """
 from __future__ import annotations
 
@@ -16,7 +21,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _gmm_kernel(sizes_ref, x_ref, w_ref, o_ref, *, block_c, precision):
+def _gmm_kernel(sizes_ref, index_ref, x_ref, w_ref, o_ref, *, block_c,
+                precision):
     e = pl.program_id(0)
     ic = pl.program_id(1)
     size = sizes_ref[e]
@@ -25,7 +31,7 @@ def _gmm_kernel(sizes_ref, x_ref, w_ref, o_ref, *, block_c, precision):
     @pl.when(live)
     def _compute():
         x = x_ref[0]                     # (block_c, D)
-        w = w_ref[0]                     # (D, block_f)
+        w = w_ref[...]                   # (D, block_f)
         acc = jax.lax.dot_general(
             x, w, (((1,), (0,)), ((), ())), precision=precision,
             preferred_element_type=jnp.float32)
@@ -40,11 +46,14 @@ def _gmm_kernel(sizes_ref, x_ref, w_ref, o_ref, *, block_c, precision):
         o_ref[0] = jnp.zeros_like(o_ref[0])
 
 
-def gmm_pallas(x, w, group_sizes, *, block_c: int = 128, block_f: int = 128,
-               interpret: bool = False):
-    """x: (E, C, D); w: (E, D, F); group_sizes: (E,).  Returns (E, C, F)."""
+def gmm_pallas(x, w, group_sizes, w_index, *, block_c: int = 128,
+               block_f: int = 128, interpret: bool = False):
+    """x: (E, C, D); w: a stack (L, E_all, D, F) with ``w_index`` int32
+    [l, e0] naming the experts w[l, e0:e0+E] (a stack the caller keeps
+    whole, e.g. a scan's loop-invariant operand, which slicing would
+    copy); group_sizes: (E,).  Returns (E, C, F)."""
     E, C, D = x.shape
-    F = w.shape[2]
+    F = w.shape[-1]
     block_c = min(block_c, C)
     block_f = min(block_f, F)
     assert C % block_c == 0 and F % block_f == 0
@@ -56,15 +65,23 @@ def gmm_pallas(x, w, group_sizes, *, block_c: int = 128, block_f: int = 128,
                  else jax.lax.Precision.DEFAULT)
     kernel = functools.partial(_gmm_kernel, block_c=block_c,
                                precision=precision)
+    nf = F // block_f
+
+    def x_block(e, i, j, sz, wi):
+        last = jnp.maximum(sz[e] - 1, 0) // block_c
+        return e, jnp.minimum(i, last), 0
+
+    def w_block(e, i, j, sz, wi):
+        return (wi[0], wi[1] + e, 0,
+                jnp.where(i * block_c < sz[e], j, nf - 1))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(E, C // block_c, F // block_f),
-        in_specs=[
-            pl.BlockSpec((1, block_c, D), lambda e, i, j, sz: (e, i, 0)),
-            pl.BlockSpec((1, D, block_f), lambda e, i, j, sz: (e, 0, j)),
-        ],
+        num_scalar_prefetch=2,
+        grid=(E, C // block_c, nf),
+        in_specs=[pl.BlockSpec((1, block_c, D), x_block),
+                  pl.BlockSpec((None, None, D, block_f), w_block)],
         out_specs=pl.BlockSpec((1, block_c, block_f),
-                               lambda e, i, j, sz: (e, i, j)),
+                               lambda e, i, j, *_: (e, i, j)),
     )
     return pl.pallas_call(
         kernel,
@@ -73,4 +90,4 @@ def gmm_pallas(x, w, group_sizes, *, block_c: int = 128, block_f: int = 128,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
-    )(group_sizes.astype(jnp.int32), x, w)
+    )(group_sizes.astype(jnp.int32), w_index.astype(jnp.int32), x, w)

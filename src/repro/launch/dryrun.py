@@ -27,6 +27,7 @@ from repro.launch.mesh import make_production_mesh, peaks  # noqa: E402
 from repro.roofline.hlo_costs import module_costs  # noqa: E402
 from repro.roofline.report import make_row  # noqa: E402
 from repro.serve import build_prefill_step, build_serve_step, cache_specs  # noqa: E402
+from repro.serve.engine import step_out_shardings  # noqa: E402
 from repro.train import build_train_step, train_state_specs  # noqa: E402
 
 RESULTS_DIR = os.environ.get("DRYRUN_DIR", "dryrun_results")
@@ -77,7 +78,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool):
         step = build_prefill_step(scfg, mesh, cache_len=shape.seq_len)
         c_specs = cache_specs(scfg, shape.global_batch, shape.seq_len)
         c_sh = cache_shardings(scfg, mesh, c_specs)
-        out_sh = {"logits": None, "cache": c_sh}
+        out_sh, _ = step_out_shardings(scfg, c_sh)
         lowered = jax.jit(step, in_shardings=(p_sh, b_sh),
                           out_shardings=out_sh).lower(p_specs, b_specs)
         return lowered, scfg, shape, mesh
@@ -90,7 +91,8 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool):
     step = build_serve_step(scfg, mesh)
     lowered = jax.jit(
         step, in_shardings=(p_sh, c_sh, b_sh["tokens"], b_sh["positions"]),
-        out_shardings=(None, c_sh), donate_argnums=(1,)).lower(
+        out_shardings=step_out_shardings(scfg, c_sh)[1],
+        donate_argnums=(1,)).lower(
             p_specs, c_specs, b_specs["tokens"], b_specs["positions"])
     return lowered, scfg, shape, mesh
 

@@ -60,7 +60,8 @@ def init_norm(cfg: ModelConfig, d: Optional[int] = None) -> Params:
     return p
 
 
-def apply_norm(cfg: ModelConfig, p: Params, x, eps: float = 1e-6):
+def apply_norm(cfg: ModelConfig, p: Params, x, eps: Optional[float] = None):
+    eps = cfg.norm_eps if eps is None else eps
     xf = x.astype(jnp.float32)
     if cfg.norm == "rmsnorm":
         xf = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
@@ -203,7 +204,7 @@ def apply_attn(cfg: ModelConfig, p: Params, x, *, kind: str, positions,
 # -- decode (single new token against a cache) ------------------------------
 
 def _decode_attention(cfg: ModelConfig, q, kc, vc, mask, k_new=None,
-                      v_new=None):
+                      v_new=None, scale: Optional[float] = None):
     """q: (B,1,H,Dh); kc/vc: (B,Sc,KH*Dh), each position's kv heads side
     by side (the decode cache's format); mask: broadcastable to (B,Sc).
     ``k_new``/``v_new`` (B,1,KH*Dh), when given, are the new token's own
@@ -215,13 +216,14 @@ def _decode_attention(cfg: ModelConfig, q, kc, vc, mask, k_new=None,
     (KH*Dh, H) query, so both contractions run over the whole lane axis
     (KH times the operations of a per-head product, against a cache read
     that costs the same either way).  Splitting the lane axis into heads
-    would relay out every layer's cache on the device."""
+    would relay out every layer's cache on the device.  ``scale``
+    defaults to the config's, else Dh**-0.5."""
     B, _, H, Dh = q.shape
     Sc, F = kc.shape[1], kc.shape[2]
     KH = F // Dh
     G = H // KH
     f32 = jnp.float32
-    scale = cfg.attn_scale or Dh ** -0.5
+    scale = scale or cfg.attn_scale or Dh ** -0.5
     sel = jnp.repeat(jnp.eye(KH, dtype=f32), Dh, axis=0)         # (F, KH)
     qf = q.astype(f32).reshape(B, KH, G, Dh) * scale
     qb = (jnp.swapaxes(qf, 2, 3).reshape(B, 1, F, G)
@@ -343,49 +345,241 @@ def attn_decode_cross(cfg: ModelConfig, p: Params, x, cache: Params):
     return out @ cast(cfg, p["wo"])
 
 
+# ---------------------------------------------------------------- MLA
+
+def init_mla(cfg: ModelConfig, key) -> Params:
+    """Latent attention without a q latent: ``wq`` (D, H*(nope+rope)),
+    ``wkv_a`` (D, rank+rope) giving the latent and the shared roped key,
+    ``kv_norm`` on the latent, ``wkv_b`` (rank, H*(nope+v)) expanding it to
+    each head's unroped key and value, ``wo`` (H*v, D)."""
+    D, H, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    std = 0.02
+    std_out = 0.02 / math.sqrt(2 * cfg.num_layers)
+    k1, k2, k3, k4 = jax.random.split(key, 4)
+    return {"wq": _normal(k1, (D, cfg.q_dim), std, _pd(cfg)),
+            "wkv_a": _normal(k2, (D, r + cfg.qk_rope_dim), std, _pd(cfg)),
+            "kv_norm": init_norm(cfg, r),
+            "wkv_b": _normal(k3, (r, H * (cfg.qk_nope_dim + cfg.v_head_dim)),
+                             std, _pd(cfg)),
+            "wo": _normal(k4, (H * cfg.v_head_dim, D), std_out, _pd(cfg))}
+
+
+def _mla_scale(cfg: ModelConfig) -> float:
+    return cfg.attn_scale or cfg.head_dim ** -0.5
+
+
+def _mla_q(cfg: ModelConfig, p: Params, x, positions):
+    """(q_nope (B,S,H,nope), q_rope (B,S,H,rope) roped)."""
+    B, S, _ = x.shape
+    q = (x @ cast(cfg, p["wq"])).reshape(B, S, cfg.num_heads, cfg.head_dim)
+    dn = cfg.qk_nope_dim
+    return q[..., :dn], apply_rope(q[..., dn:], positions, cfg.rope_theta)
+
+
+def mla_latent(cfg: ModelConfig, p: Params, x, positions):
+    """One cache row a position: the RMS-normed latent beside the roped
+    key that every head shares, (B, S, rank + rope)."""
+    r = cfg.kv_lora_rank
+    kva = x @ cast(cfg, p["wkv_a"])
+    c = apply_norm(cfg, p["kv_norm"], kva[..., :r])
+    k_r = apply_rope(kva[..., None, r:], positions, cfg.rope_theta)[:, :, 0]
+    return jnp.concatenate([c, k_r.astype(c.dtype)], axis=-1)
+
+
+def apply_mla(cfg: ModelConfig, p: Params, x, *, positions, seg_ids=None):
+    """Prefill / training: the latent expanded to per-head keys and values
+    (k = [W_UK c ; k_rope], v = W_UV c), causal attention over q/k width
+    nope+rope and value width v.  Returns (out (B,S,D), latent rows
+    (B,S,rank+rope), the decode cache's format)."""
+    B, S, _ = x.shape
+    H, r, dn, dv = (cfg.num_heads, cfg.kv_lora_rank, cfg.qk_nope_dim,
+                    cfg.v_head_dim)
+    q_nope, q_rope = _mla_q(cfg, p, x, positions)
+    row = mla_latent(cfg, p, x, positions)
+    kv = (row[..., :r] @ cast(cfg, p["wkv_b"])).reshape(B, S, H, dn + dv)
+    k_rope = jnp.broadcast_to(row[:, :, None, r:],
+                              (B, S, H, cfg.qk_rope_dim))
+    q = jnp.concatenate([q_nope, q_rope.astype(q_nope.dtype)], axis=-1)
+    k = jnp.concatenate([kv[..., :dn], k_rope], axis=-1)
+    o = flash_attention(q, k, kv[..., dn:], causal=True,
+                        scale=_mla_scale(cfg), seg_q=seg_ids, seg_kv=seg_ids,
+                        impl=_impl(cfg))
+    return o.reshape(B, S, H * dv) @ cast(cfg, p["wo"]), row
+
+
+def mla_decode(cfg: ModelConfig, p: Params, x, cache: Params, positions):
+    """Absorbed decode: each head's query is moved into the latent space
+    (q_lat_h = W_UK_h^T q_nope_h, beside q_rope_h), scored against the
+    cache's rows [c ; k_rope] (rank+rope lanes, one "kv head" that all
+    heads share) and the new row, and its output is W_UV_h applied to the
+    weighted sum of latents.  The cache ``ckv`` (B, S, rank+rope) is only
+    read; the new row is returned for ``decode_step`` to write at
+    ``positions``, as for any full-length cache."""
+    B = x.shape[0]
+    H, r, dn = cfg.num_heads, cfg.kv_lora_rank, cfg.qk_nope_dim
+    pos = positions[:, None]
+    q_nope, q_rope = _mla_q(cfg, p, x, pos)
+    row = mla_latent(cfg, p, x, pos)
+    wkv_b = cast(cfg, p["wkv_b"]).reshape(r, H, dn + cfg.v_head_dim)
+    q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, wkv_b[..., :dn])
+    qc = jnp.concatenate([q_lat, q_rope.astype(q_lat.dtype)], axis=-1)
+    S = cache["ckv"].shape[1]
+    mask = jnp.arange(S)[None, :] < positions[:, None]
+    o = _decode_attention(cfg, qc, cache["ckv"], cache["ckv"], mask, row,
+                          row, scale=_mla_scale(cfg))
+    o_lat = o.reshape(B, 1, H, r + cfg.qk_rope_dim)[..., :r]
+    o = jnp.einsum("bshr,rhd->bshd", o_lat, wkv_b[..., dn:])
+    return o.reshape(B, 1, -1) @ cast(cfg, p["wo"]), {"ckv": row}
+
+
 # ---------------------------------------------------------------- MoE
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
+ROUTER_BIAS_STD = 0.1   # e_score_correction_bias drawn from the seed
+
+# rows of one expert pass of the held-expert layout: each expert's buffer
+# holds ``capacity`` rows, so a prefill of many tokens runs its experts a
+# few at a time (one per pass at 32k tokens) to bound the transient buffers
+EXPERT_PASS_ROWS = 32_768
+
+
 def init_moe(cfg: ModelConfig, key) -> Params:
+    """Router over all ``num_experts`` (and, for ``sigmoid_bias``, the
+    selection bias), the held experts' weights and the shared experts.
+    Expert ``e`` draws its weights from ``fold_in(k, e)`` of its global
+    id, so a rank that holds experts [base, base+held) holds exactly the
+    uncut model's weights of those experts."""
     D, F, E = cfg.d_model, cfg.expert_ff, cfg.num_experts
     std = 0.02
     std_out = 0.02 / math.sqrt(2 * cfg.num_layers)
-    k1, k2, k3, k4 = jax.random.split(key, 4)
+    k1, k2, k3, k4, k5, k6 = jax.random.split(key, 6)
+    base = cfg.expert_rank * cfg.held_experts
+    ids = jnp.arange(base, base + cfg.held_experts)
+
+    def experts(k, shape, sd):
+        return jax.vmap(lambda e: _normal(jax.random.fold_in(k, e), shape,
+                                          sd, _pd(cfg)))(ids)
+
     p = {"router": _normal(k1, (D, E), std, jnp.float32),
-         "wi": _normal(k2, (E, D, F), std, _pd(cfg)),
-         "wo": _normal(k3, (E, F, D), std_out, _pd(cfg))}
+         "wi": experts(k2, (D, F), std),
+         "wo": experts(k3, (F, D), std_out)}
     if cfg.mlp in ("swiglu", "geglu"):
-        p["wg"] = _normal(k4, (E, D, F), std, _pd(cfg))
+        p["wg"] = experts(k4, (D, F), std)
+    if cfg.router == "sigmoid_bias":
+        p["bias"] = _normal(k5, (E,), ROUTER_BIAS_STD, jnp.float32)
+    if cfg.num_shared_experts:
+        p["shared"] = init_mlp(cfg, k6, cfg.num_shared_experts * F)
     return p
+
+
+def route(cfg: ModelConfig, p: Params, xt):
+    """Routing of tokens ``xt`` (T, D) over all ``num_experts``, in f32.
+    Returns (weights (T, k), expert ids (T, k), aux load-balance loss).
+
+    ``softmax``: top-k of the softmax, renormalised.  ``sigmoid_bias``
+    (DeepSeek-V3 noaux_tc, one group): s = sigmoid(x W_r); the top-k of
+    s + bias choose the experts; their unbiased s, divided by its sum and
+    times ``routed_scale``, weigh them."""
+    E, k = cfg.num_experts, cfg.experts_per_tok
+    logits = xt.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+    if cfg.router == "sigmoid_bias":
+        s = jax.nn.sigmoid(logits)
+        _, idx = lax.top_k(s + p["bias"].astype(jnp.float32), k)
+        wts = jnp.take_along_axis(s, idx, axis=-1)
+        wts = wts / (wts.sum(-1, keepdims=True) + 1e-20) * cfg.routed_scale
+        probs = s / s.sum(-1, keepdims=True)
+    elif cfg.router == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        wts, idx = lax.top_k(probs, k)
+        wts = wts / jnp.maximum(wts.sum(-1, keepdims=True), 1e-9)
+    else:
+        raise ValueError(cfg.router)
+    # aux loss (switch-style), computed over full expert population
+    T = xt.shape[0]
+    f = jnp.zeros((E,), jnp.float32).at[idx.reshape(-1)].add(1.0) / (T * k)
+    aux = E * jnp.sum(f * jnp.mean(probs, axis=0))
+    return wts, idx, aux
+
+
+def _expert_pass(E_local: int, C: int) -> int:
+    """Experts per pass: the most that divide ``E_local`` and keep a pass
+    within ``EXPERT_PASS_ROWS`` rows (at least one)."""
+    g = max(1, min(E_local, EXPERT_PASS_ROWS // max(C, 1)))
+    while E_local % g:
+        g -= 1
+    return g
+
+
+def _expert_ffn(cfg: ModelConfig, p: Params, xt, tok, sizes, pos, wt):
+    """The held experts' weighted sum for each token.  ``tok`` (E_local, C)
+    lists each expert's rows by token id (``T`` for an empty row, which
+    reads a zero row), ``sizes`` (E_local,) each expert's row count: the
+    grouped matmul skips the row blocks past it.  ``pos`` (E_local, T) is
+    each token's row in each expert's list (``C`` where it has none) and
+    ``wt`` (E_local, T) its routing weight there (0 where it has none):
+    each token gathers its rows back, so no scatter of the mostly empty
+    rows.  The expert weights are this layer's (E_local, D, F), or the
+    whole stack (layers, E_local, D, F) with ``p["layer"]`` its index
+    (``transformer.scan_stack``); either way the kernel is handed the
+    array and an index, never a slice.  Returns (T, D) f32."""
+    T, D = xt.shape
+    E_local, C = tok.shape
+    xpad = jnp.concatenate([xt, jnp.zeros((1, D), xt.dtype)])
+    layer = p.get("layer", 0)
+    ws = {k: cast(cfg, p[k] if "layer" in p else p[k][None])
+          for k in ("wi", "wg", "wo") if k in p}
+
+    def one(y, xs):
+        tok_g, sz_g, e0, pos_g, wt_g = xs
+        g = tok_g.shape[0]
+        idx = jnp.stack([layer, e0]).astype(jnp.int32)
+        xe = xpad[tok_g]                                    # (g, C, D)
+        hi = gmm(xe, ws["wi"], sz_g, idx, impl=_impl(cfg))
+        hg = gmm(xe, ws["wg"], sz_g, idx, impl=_impl(cfg)) \
+            if "wg" in ws else None
+        ye = gmm(_mlp_act(cfg, hi, hg), ws["wo"], sz_g, idx, impl=_impl(cfg))
+        # a token without a row in an expert reads its last row, at weight 0
+        rows = ye[jnp.arange(g)[:, None], jnp.minimum(pos_g, C - 1)]
+        return y + jnp.sum(wt_g[..., None] * rows.astype(jnp.float32),
+                           axis=0), None
+
+    y = jnp.zeros((T, D), jnp.float32)
+    g = _expert_pass(E_local, C)
+    if g == E_local:
+        y, _ = one(y, (tok, sizes, 0, pos, wt))
+    else:
+        split = lambda a: a.reshape((E_local // g, g) + a.shape[1:])  # noqa: E731
+        y, _ = lax.scan(one, y, (split(tok), split(sizes),
+                                 jnp.arange(0, E_local, g), split(pos),
+                                 split(wt)))
+    return y
 
 
 def _moe_local(cfg: ModelConfig, p: Params, xt, e_base, E_local: int,
                capacity_factor: float):
-    """Sort+scatter dispatch for the local expert slice [e_base, e_base+E_local).
+    """Sorted dispatch for the local expert slice [e_base, e_base+E_local).
 
     xt: (T, D) local tokens.  ``p["wi"/"wg"/"wo"]`` hold the E_local-sized
-    slice already (shard_map in_specs deliver the local shard); ``e_base``
-    may be traced (lax.axis_index).  Returns (y (T, D) partial sum over
-    local experts, aux load-balance loss over the full expert population).
+    slice already (shard_map in_specs deliver the local shard; a rank
+    that holds ``experts_held`` gets its own from ``init_moe``); ``e_base``
+    may be traced (lax.axis_index).  Each expert takes at most
+    ``capacity`` rows: ``capacity_factor`` times its even share, or, at
+    ``capacity_factor`` 0 (dropless), all T tokens, which no routing can
+    exceed (a token picks an expert once).  Returns (y (T, D) partial sum
+    over local experts, aux load-balance loss over the full expert
+    population, int32 [rows the local experts computed, local experts
+    with a row, rows routed here but dropped past capacity]).
     """
     T, D = xt.shape
     E, k = cfg.num_experts, cfg.experts_per_tok
-    logits = xt.astype(jnp.float32) @ p["router"].astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)                      # (T, E)
-    wts, idx = lax.top_k(probs, k)                               # (T, k)
-    wts = wts / jnp.maximum(wts.sum(-1, keepdims=True), 1e-9)
+    wts, idx, aux = route(cfg, p, xt)
 
     eids = idx.reshape(-1)                                       # (T*k,)
     tids = jnp.repeat(jnp.arange(T), k)
     wv = wts.reshape(-1)
-
-    # aux loss (switch-style), computed over full expert population
-    f = jnp.zeros((E,), jnp.float32).at[eids].add(1.0) / (T * k)
-    pbar = jnp.mean(probs, axis=0)
-    aux = E * jnp.sum(f * pbar)
 
     el = eids - e_base
     inrange = (el >= 0) & (el < E_local)
@@ -400,43 +594,62 @@ def _moe_local(cfg: ModelConfig, p: Params, xt, e_base, E_local: int,
                             jnp.cumsum(counts)[:-1]])
     pos_in_e = jnp.arange(T * k) - offs[el_s]
 
-    cap_block = 128 if T * k // max(E_local, 1) >= 128 else 8
-    C = max(cap_block,
-            _round_up(int(math.ceil(T * k / E * capacity_factor)), cap_block))
+    if capacity_factor:
+        cap_block = 128 if T * k // max(E_local, 1) >= 128 else 8
+        C = max(cap_block, _round_up(
+            int(math.ceil(T * k / E * capacity_factor)), cap_block))
+    else:
+        C = _round_up(T, 128 if T >= 128 else 8)
     keep = (pos_in_e < C) & (el_s < E_local)
     slot = jnp.where(keep, el_s * C + pos_in_e, E_local * C)
 
-    xe = jnp.zeros((E_local * C + 1, D), xt.dtype)
-    xe = xe.at[slot].set(xt[tid_s] * keep[:, None].astype(xt.dtype))
-    xe = xe[:-1].reshape(E_local, C, D)
-    group_sizes = jnp.minimum(counts[:E_local], C)
-
-    hi = gmm(xe, cast(cfg, p["wi"]), group_sizes, impl=_impl(cfg))
-    hg = gmm(xe, cast(cfg, p["wg"]), group_sizes, impl=_impl(cfg)) \
-        if "wg" in p else None
-    h = _mlp_act(cfg, hi, hg)
-    ye = gmm(h, cast(cfg, p["wo"]), group_sizes, impl=_impl(cfg))
-
-    flat = jnp.concatenate([ye.reshape(E_local * C, D),
-                            jnp.zeros((1, D), ye.dtype)])
-    back = flat[slot] * (keep & inrange[order])[:, None].astype(ye.dtype)
-    y = jnp.zeros((T, D), xt.dtype).at[tid_s].add(
-        back * w_s[:, None].astype(ye.dtype))
-    return y, aux
+    tok = jnp.full((E_local * C + 1,), T, jnp.int32).at[slot].set(tid_s)
+    # each token's row and weight in each held expert, for the gather back
+    e_k = jnp.where(keep, el_s, E_local)
+    t_k = jnp.where(keep, tid_s, T)
+    pos = jnp.full((E_local + 1, T + 1), C, jnp.int32).at[e_k, t_k].set(
+        pos_in_e)
+    wt = jnp.zeros((E_local + 1, T + 1), jnp.float32).at[e_k, t_k].set(w_s)
+    sizes = jnp.minimum(counts[:E_local], C)
+    y = _expert_ffn(cfg, p, xt, tok[:-1].reshape(E_local, C), sizes,
+                    pos[:E_local, :T], wt[:E_local, :T])
+    rows = jnp.sum(sizes)
+    stats = jnp.stack([rows, jnp.sum(sizes > 0),
+                       jnp.sum(counts[:E_local]) - rows]).astype(jnp.int32)
+    return y.astype(xt.dtype), aux, stats
 
 
-def apply_moe(cfg: ModelConfig, p: Params, x, *, mesh=None,
-              capacity_factor: float = 1.25):
-    """Returns (y, aux_loss).  EP via shard_map when mesh has a 'model' axis
-    and the profile is tp_ep; otherwise dispatch is local per data shard
-    (expert weights TP-sharded by GSPMD for the grok-style profile)."""
+def moe_layer(cfg: ModelConfig, p: Params, x, *, mesh=None):
+    """The expert MLP: the held (or local) experts' part of the routed sum,
+    plus the shared experts whole.  Returns (y, aux_loss, stats): ``stats``
+    int32 [expert rows computed, held experts with a row, rows dropped].
+    EP via shard_map when mesh has a 'model' axis and the profile is
+    tp_ep; otherwise dispatch is local per data shard (expert weights
+    TP-sharded by GSPMD for the grok-style profile).  A rank holding a
+    share of the experts (``experts_held``) runs without a mesh: its
+    exchange with the other ranks is not part of this layer."""
     B, S, D = x.shape
-    E = cfg.num_experts
+    cf = cfg.moe_capacity
+    routed = {k: v for k, v in p.items() if k != "shared"}
 
     if mesh is None:
-        y, aux = _moe_local(cfg, p, x.reshape(-1, D), 0, E, capacity_factor)
-        return y.reshape(B, S, D), aux
+        E_local = cfg.held_experts
+        y, aux, stats = _moe_local(cfg, routed, x.reshape(-1, D),
+                                   cfg.expert_rank * E_local, E_local, cf)
+        y = y.reshape(B, S, D)
+    elif cfg.experts_held:
+        raise ValueError("a rank holding experts_held experts runs "
+                         "without a mesh")
+    else:
+        y, aux, stats = _moe_mesh(cfg, routed, x, mesh, cf)
+    if "shared" in p:
+        y = y + apply_mlp(cfg, p["shared"], x)
+    return y, aux, stats
 
+
+def _moe_mesh(cfg: ModelConfig, p: Params, x, mesh, capacity_factor):
+    B, S, D = x.shape
+    E = cfg.num_experts
     data_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     if cfg.sharding_profile == "tp_ep":
         mdl = mesh.shape["model"]
@@ -445,20 +658,19 @@ def apply_moe(cfg: ModelConfig, p: Params, x, *, mesh=None,
         def f(xb, pl):
             T = xb.shape[0] * xb.shape[1]
             j = lax.axis_index("model")
-            y, aux = _moe_local(cfg, pl, xb.reshape(T, D),
-                                j * E_local, E_local, capacity_factor)
+            y, aux, st = _moe_local(cfg, pl, xb.reshape(T, D),
+                                    j * E_local, E_local, capacity_factor)
             y = lax.psum(y, "model")
             aux = lax.pmean(aux, data_axes)
-            return y.reshape(xb.shape), aux
+            st = lax.psum(st, ("model",) + data_axes)
+            return y.reshape(xb.shape), aux, st
 
-        pspecs = {"router": P(None, None), "wi": P("model", None, None),
-                  "wo": P("model", None, None)}
-        if "wg" in p:
-            pspecs["wg"] = P("model", None, None)
+        pspecs = {k: (P("model", None, None) if k in ("wi", "wg", "wo")
+                      else P()) for k in p}
         return jax.shard_map(
             f, mesh=mesh,
             in_specs=(P(data_axes, None, None), pspecs),
-            out_specs=(P(data_axes, None, None), P()),
+            out_specs=(P(data_axes, None, None), P(), P()),
             check_vma=False)(x, p)
 
     # tp profile (few big experts): dispatch local per data shard; expert
@@ -470,15 +682,17 @@ def apply_moe(cfg: ModelConfig, p: Params, x, *, mesh=None,
     # f32 gather stands on this backend; EXPERIMENTS.md §Perf grok.
     def f(xb, pl):
         T = xb.shape[0] * xb.shape[1]
-        y, aux = _moe_local(cfg, pl, xb.reshape(T, D), 0, E, capacity_factor)
+        y, aux, st = _moe_local(cfg, pl, xb.reshape(T, D), 0, E,
+                                capacity_factor)
         aux = lax.pmean(aux, data_axes)
-        return y.reshape(xb.shape), aux
+        st = lax.psum(st, data_axes)
+        return y.reshape(xb.shape), aux, st
 
     pspecs = jax.tree.map(lambda _: P(), p)
     return jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(data_axes, None, None), pspecs),
-        out_specs=(P(data_axes, None, None), P()),
+        out_specs=(P(data_axes, None, None), P(), P()),
         axis_names=set(data_axes),
         check_vma=False)(x, p)
 

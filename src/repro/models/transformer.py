@@ -26,18 +26,21 @@ Params = Dict[str, Any]
 # ---------------------------------------------------------------- init
 
 def _init_block(cfg: ModelConfig, key, kind: str, *, cross: bool = False,
-                enc: bool = False) -> Params:
+                enc: bool = False, dense: bool = False) -> Params:
+    """One layer's parameters; ``dense``: a leading layer that keeps the
+    dense MLP in a model with experts."""
     ks = jax.random.split(key, 8)
     p: Params = {"ln1": L.init_norm(cfg)}
     if kind in ("global", "local", "enc"):
-        p["attn"] = L.init_attn(cfg, ks[0])
+        p["attn"] = (L.init_mla(cfg, ks[0]) if cfg.mla and not enc
+                     else L.init_attn(cfg, ks[0]))
         if cfg.post_norms:
             p["ln1_post"] = L.init_norm(cfg)
         if cross:
             p["lnx"] = L.init_norm(cfg)
             p["xattn"] = L.init_attn(cfg, ks[1], cross=True)
         p["ln2"] = L.init_norm(cfg)
-        if cfg.num_experts and not enc:
+        if cfg.num_experts and not enc and not dense:
             p["moe"] = L.init_moe(cfg, ks[2])
         else:
             p["mlp"] = L.init_mlp(cfg, ks[2])
@@ -55,12 +58,52 @@ def _init_block(cfg: ModelConfig, key, kind: str, *, cross: bool = False,
 
 
 def _layout(cfg: ModelConfig) -> Tuple[int, int, int]:
-    """(period, num_scanned_groups, num_tail_layers)."""
+    """(period, num_scanned_groups, num_tail_layers) of the layers after
+    the ``first_dense_layers``, which are unrolled ahead of the stack
+    (params and cache under ``dense``/``block_<j>``).  The scanned stack's
+    sub-layer ``s`` has kind ``layer_pattern[s]``."""
     period = len(cfg.layer_pattern)
+    n = cfg.num_layers - cfg.first_dense_layers
     if not cfg.scan_layers:
-        return period, 0, cfg.num_layers
-    G = cfg.num_layers // period
-    return period, G, cfg.num_layers - G * period
+        return period, 0, n
+    G = n // period
+    return period, G, n - G * period
+
+
+def _kind(cfg: ModelConfig, G: int, period: int, j: int) -> str:
+    """Kind of tail layer ``j``."""
+    return cfg.layer_kind(cfg.first_dense_layers + G * period + j)
+
+
+def scan_stack(cfg: ModelConfig, blocks: Params):
+    """(xs, layer) for a scan over the stacked layers ``blocks``: the body
+    gets its layer's parameters as ``layer(x)`` of its slice ``x``.
+
+    With the Pallas expert kernel (forward only: prefill and decode) the
+    expert weights stay out of ``xs``: the body gets the whole stacks and
+    the layer index, which the kernel reads in place.  Sliced per
+    iteration, a custom call's operand is copied out of the stack: three
+    copies of (held experts, D, F) a layer in every step.  Otherwise
+    ``xs`` is ``blocks`` itself: the differentiable paths keep the slices,
+    whose gradient is one layer's, where a stack read by index would
+    carry a whole stack's gradient through every iteration."""
+    from repro import flags
+    hoist = {s: {k: sub["moe"][k] for k in ("wi", "wg", "wo")
+                 if k in sub["moe"]}
+             for s, sub in blocks.items() if "moe" in sub}
+    if not hoist or flags.moe_impl(L._impl(cfg)) not in (
+            "pallas", "pallas_interpret"):
+        return blocks, lambda bp: bp
+    G = jax.tree.leaves(blocks)[0].shape[0]
+    xs = {s: {**sub, "moe": {k: v for k, v in sub["moe"].items()
+                              if k not in hoist[s]}} if s in hoist else sub
+          for s, sub in blocks.items()}
+
+    def layer(x):
+        bp, i = x
+        return {s: {**sub, "moe": {**sub["moe"], **hoist[s], "layer": i}}
+                if s in hoist else sub for s, sub in bp.items()}
+    return (xs, jnp.arange(G)), layer
 
 
 def init_params(cfg: ModelConfig, key) -> Params:
@@ -75,6 +118,13 @@ def init_params(cfg: ModelConfig, key) -> Params:
 
     period, G, n_tail = _layout(cfg)
     cross = cfg.encoder_layers > 0
+    if cfg.first_dense_layers:
+        # keys folded from the head's, leaving every other draw as it was
+        params["dense"] = {
+            f"block_{j}": _init_block(
+                cfg, jax.random.fold_in(kH, j + 1), cfg.layer_kind(j),
+                cross=cross, dense=True)
+            for j in range(cfg.first_dense_layers)}
 
     def init_group(k):
         sub = {}
@@ -88,9 +138,9 @@ def init_params(cfg: ModelConfig, key) -> Params:
         params["blocks"] = jax.vmap(init_group)(jax.random.split(kB, G))
     tail = {}
     for j in range(n_tail):
-        i = G * period + j
         tail[f"block_{j}"] = _init_block(
-            cfg, jax.random.fold_in(kT, j), cfg.layer_kind(i), cross=cross)
+            cfg, jax.random.fold_in(kT, j), _kind(cfg, G, period, j),
+            cross=cross)
     if tail:
         params["tail"] = tail
 
@@ -107,14 +157,27 @@ def init_params(cfg: ModelConfig, key) -> Params:
 
 # ---------------------------------------------------------------- blocks
 
+def _no_stats():
+    return jnp.zeros((3,), jnp.int32)
+
+
 def forward_block(cfg: ModelConfig, bp: Params, h, kind: str, *, positions,
                   seg_ids, mem, mesh, cache_len: Optional[int]):
-    """Returns (h, aux, cache_or_None)."""
+    """Returns (h, aux, cache_or_None, stats): ``stats`` the expert layer's
+    int32 counts (``layers.moe_layer``), zeros without one."""
     aux = jnp.zeros((), jnp.float32)
+    stats = _no_stats()
     cache = None
     if kind in ("global", "local", "enc"):
         xin = L.apply_norm(cfg, bp["ln1"], h)
-        if cache_len:
+        if cfg.mla and kind != "enc":
+            a, row = L.apply_mla(cfg, bp["attn"], xin, positions=positions,
+                                 seg_ids=seg_ids)
+            if cache_len:
+                S = row.shape[1]
+                cache = {"ckv": jnp.pad(
+                    row, ((0, 0), (0, cache_len - S), (0, 0)))}
+        elif cache_len:
             a, kv = _attn_with_cache(cfg, bp["attn"], xin, kind=kind,
                                      positions=positions, seg_ids=seg_ids,
                                      mesh=mesh, cache_len=cache_len)
@@ -136,7 +199,7 @@ def forward_block(cfg: ModelConfig, bp: Params, h, kind: str, *, positions,
             h = h + xa
         xin = L.apply_norm(cfg, bp["ln2"], h)
         if "moe" in bp:
-            y, aux = L.apply_moe(cfg, bp["moe"], xin, mesh=mesh)
+            y, aux, stats = L.moe_layer(cfg, bp["moe"], xin, mesh=mesh)
         else:
             y = L.apply_mlp(cfg, bp["mlp"], xin)
         if cfg.post_norms:
@@ -162,7 +225,7 @@ def forward_block(cfg: ModelConfig, bp: Params, h, kind: str, *, positions,
         h = h + m
     else:
         raise ValueError(kind)
-    return h, aux, cache
+    return h, aux, cache, stats
 
 
 def _attn_with_cache(cfg, p, x, *, kind, positions, seg_ids, mesh, cache_len):
@@ -212,16 +275,22 @@ def _cross_with_cache(cfg, p, x, mem):
 
 def decode_block(cfg: ModelConfig, bp: Params, h, cache: Params, kind: str,
                  *, positions, mesh):
-    """Single-token step.  h: (B,1,D).  Returns (h, upd): ``upd`` holds
-    only the cache entries the step changes — the new k/v rows of a
-    full-length attention cache (``decode_step`` writes them), or the
-    whole new ring / recurrent state; cross-attention ``xk``/``xv`` are
-    read, never returned."""
+    """Single-token step.  h: (B,1,D).  Returns (h, upd, stats): ``upd``
+    holds only the cache entries the step changes — the new rows of a
+    full-length attention cache (k/v, or MLA's latent ``ckv``;
+    ``decode_step`` writes them), or the whole new ring / recurrent
+    state; cross-attention ``xk``/``xv`` are read, never returned.
+    ``stats`` as ``forward_block``'s."""
+    stats = _no_stats()
     if kind in ("global", "local"):
         xin = L.apply_norm(cfg, bp["ln1"], h)
-        sub = {k: cache[k] for k in ("k", "v", "pos") if k in cache}
-        a, upd = L.attn_decode(cfg, bp["attn"], xin, sub, positions,
-                               kind=kind, mesh=mesh)
+        if cfg.mla:
+            a, upd = L.mla_decode(cfg, bp["attn"], xin,
+                                  {"ckv": cache["ckv"]}, positions)
+        else:
+            sub = {k: cache[k] for k in ("k", "v", "pos") if k in cache}
+            a, upd = L.attn_decode(cfg, bp["attn"], xin, sub, positions,
+                                   kind=kind, mesh=mesh)
         if cfg.post_norms:
             a = L.apply_norm(cfg, bp["ln1_post"], a)
         h = h + a
@@ -232,7 +301,7 @@ def decode_block(cfg: ModelConfig, bp: Params, h, cache: Params, kind: str,
             h = h + xa
         xin = L.apply_norm(cfg, bp["ln2"], h)
         if "moe" in bp:
-            y, _ = L.apply_moe(cfg, bp["moe"], xin, mesh=mesh)
+            y, _, stats = L.moe_layer(cfg, bp["moe"], xin, mesh=mesh)
         else:
             y = L.apply_mlp(cfg, bp["mlp"], xin)
         if cfg.post_norms:
@@ -251,7 +320,7 @@ def decode_block(cfg: ModelConfig, bp: Params, h, cache: Params, kind: str,
         h = h + m
     else:
         raise ValueError(kind)
-    return h, upd
+    return h, upd, stats
 
 
 # ---------------------------------------------------------------- embed/head
@@ -291,9 +360,9 @@ def encode(cfg: ModelConfig, params: Params, enc_frames, *, mesh=None,
 
     def body(carry, bp):
         hh = carry
-        hh, _, _ = forward_block(cfg, bp["sub_0"], hh, "enc", positions=pos,
-                                 seg_ids=None, mem=None, mesh=mesh,
-                                 cache_len=None)
+        hh, _, _, _ = forward_block(cfg, bp["sub_0"], hh, "enc",
+                                    positions=pos, seg_ids=None, mem=None,
+                                    mesh=mesh, cache_len=None)
         hh = constrain_batch(cfg, mesh, hh, batch_kind)
         return hh, None
 
@@ -336,51 +405,61 @@ def forward(cfg: ModelConfig, params: Params, tokens, *, positions=None,
 
     period, G, n_tail = _layout(cfg)
     aux = jnp.zeros((), jnp.float32)
+    stats = _no_stats()
     collect = cache_len is not None
     cache: Params = {}
 
-    if G:
-        def body(carry, bp):
-            hh, ax = carry
-            cg = {}
-            for s in range(period):
-                kind = cfg.layer_pattern[s]
-                hh, a, c = forward_block(cfg, bp[f"sub_{s}"], hh, kind,
-                                         positions=positions, seg_ids=seg_ids,
-                                         mem=mem, mesh=mesh,
-                                         cache_len=cache_len)
-                ax = ax + a
-                hh = constrain_batch(cfg, mesh, hh, batch_kind)
-                if collect:
-                    cg[f"sub_{s}"] = c
-            return (hh, ax), (cg if collect else None)
-
-        if remat:
-            body = jax.checkpoint(body, policy=_remat_policy(cfg))
-        (h, aux), blocks_cache = lax.scan(body, (h, aux), params["blocks"])
-        if collect:
-            cache["blocks"] = blocks_cache
-
-    if n_tail:
-        tail_cache = {}
-        for j in range(n_tail):
-            i = G * period + j
-            kind = cfg.layer_kind(i)
+    def unrolled(h, aux, stats, group: str, kinds):
+        """Layers kept out of the scan: the leading dense ones and the
+        tail."""
+        out = {}
+        for j, kind in enumerate(kinds):
             blk = lambda hh, bp, kind=kind: forward_block(
                 cfg, bp, hh, kind, positions=positions, seg_ids=seg_ids,
                 mem=mem, mesh=mesh, cache_len=cache_len)
             if remat:
                 blk = jax.checkpoint(blk, policy=_remat_policy(cfg))
-            h, a, c = blk(h, params["tail"][f"block_{j}"])
+            h, a, c, st = blk(h, params[group][f"block_{j}"])
             h = constrain_batch(cfg, mesh, h, batch_kind)
-            aux = aux + a
-            if collect:
-                tail_cache[f"block_{j}"] = c
+            aux, stats = aux + a, stats + st
+            out[f"block_{j}"] = c
+        if collect and kinds:
+            cache[group] = out
+        return h, aux, stats
+
+    h, aux, stats = unrolled(h, aux, stats, "dense", [
+        cfg.layer_kind(j) for j in range(cfg.first_dense_layers)])
+
+    if G:
+        xs, layer = scan_stack(cfg, params["blocks"])
+
+        def body(carry, bp):
+            hh, ax, st = carry
+            bp = layer(bp)
+            cg = {}
+            for s in range(period):
+                kind = cfg.layer_pattern[s]
+                hh, a, c, sb = forward_block(
+                    cfg, bp[f"sub_{s}"], hh, kind, positions=positions,
+                    seg_ids=seg_ids, mem=mem, mesh=mesh, cache_len=cache_len)
+                ax, st = ax + a, st + sb
+                hh = constrain_batch(cfg, mesh, hh, batch_kind)
+                if collect:
+                    cg[f"sub_{s}"] = c
+            return (hh, ax, st), (cg if collect else None)
+
+        if remat:
+            body = jax.checkpoint(body, policy=_remat_policy(cfg))
+        (h, aux, stats), blocks_cache = lax.scan(body, (h, aux, stats), xs)
         if collect:
-            cache["tail"] = tail_cache
+            cache["blocks"] = blocks_cache
+
+    h, aux, stats = unrolled(h, aux, stats, "tail", [
+        _kind(cfg, G, period, j) for j in range(n_tail)])
 
     h = L.apply_norm(cfg, params["final_norm"], h)
-    return {"h": h, "aux": aux, "cache": cache if collect else None}
+    return {"h": h, "aux": aux, "cache": cache if collect else None,
+            "moe": stats}
 
 
 # ---------------------------------------------------------------- decode
@@ -388,12 +467,13 @@ def forward(cfg: ModelConfig, params: Params, tokens, *, positions=None,
 def _apply_updates(cfg: ModelConfig, kind: str, cache: Params, upd: Params,
                    positions, *, axis: int, mesh) -> Params:
     """One layer's (or scanned sub-layer's) new cache: ``decode_block``'s
-    new k/v rows written at ``positions`` into a full-length attention
-    cache, or its whole new state put in place; entries it did not return
-    (cross-attention ``xk``/``xv``) pass through."""
+    new rows (k/v, or MLA's ``ckv``) written at ``positions`` into a
+    full-length attention cache, or its whole new state put in place;
+    entries it did not return (cross-attention ``xk``/``xv``) pass
+    through."""
     new = dict(cache)
     if kind in ("global", "local") and not L.ring_cache(cfg, kind):
-        for name in ("k", "v"):
+        for name in upd:
             new[name] = L.write_rows(cfg, cache[name], upd[name],
                                      positions, axis=axis, mesh=mesh)
     else:
@@ -402,56 +482,73 @@ def _apply_updates(cfg: ModelConfig, kind: str, cache: Params, upd: Params,
 
 
 def decode_step(cfg: ModelConfig, params: Params, cache: Params, tokens,
-                positions, *, mesh=None):
+                positions, *, mesh=None, stats: bool = False):
     """One token for the whole batch.  tokens: (B,1); positions: (B,) —
     per-row offsets: rows may sit at different sequence positions (see
     layers.attn_decode), which is what lets the continuous-batching server
     admit a freshly prefilled request into a running decode wave.
 
     The layer scan only reads full-length attention caches and emits each
-    layer's new k/v rows; one write per leaf after the scan puts them at
+    layer's new rows; one write per leaf after the scan puts them at
     ``positions`` into the stacked cache, which XLA updates in place when
     the caller donates ``cache`` (``BatchedServer`` does).  Sliding-window
     rings and recurrent states are small and re-emitted whole by the scan.
-    Returns (logits (B,1,V), new cache)."""
+    Returns (logits (B,1,V), new cache), and with ``stats`` also the
+    expert layers' int32 counts summed over layers (``layers.moe_layer``).
+    """
     h = embed_tokens(cfg, params, tokens, positions[:, None])
     h = constrain_batch(cfg, mesh, h, "serve")
     period, G, n_tail = _layout(cfg)
     new_cache: Params = {}
+    st = _no_stats()
+
+    def unrolled(h, st, group: str, kinds):
+        out = {}
+        for j, kind in enumerate(kinds):
+            c = cache[group][f"block_{j}"]
+            h, upd, sb = decode_block(cfg, params[group][f"block_{j}"], h, c,
+                                      kind, positions=positions, mesh=mesh)
+            st = st + sb
+            out[f"block_{j}"] = _apply_updates(cfg, kind, c, upd, positions,
+                                               axis=0, mesh=mesh)
+        if kinds:
+            new_cache[group] = out
+        return h, st
+
+    h, st = unrolled(h, st, "dense", [
+        cfg.layer_kind(j) for j in range(cfg.first_dense_layers)])
 
     if G:
+        xs, layer = scan_stack(cfg, params["blocks"])
+
         def body(carry, xs):
-            hh = carry
+            hh, sc = carry
             bp, cg = xs
+            bp = layer(bp)
             ug = {}
             for s in range(period):
                 kind = cfg.layer_pattern[s]
-                hh, ug[f"sub_{s}"] = decode_block(
+                hh, ug[f"sub_{s}"], sb = decode_block(
                     cfg, bp[f"sub_{s}"], hh, cg[f"sub_{s}"], kind,
                     positions=positions, mesh=mesh)
+                sc = sc + sb
             hh = constrain_batch(cfg, mesh, hh, "serve")
-            return hh, ug
+            return (hh, sc), ug
 
-        h, ubc = lax.scan(body, h, (params["blocks"], cache["blocks"]))
+        (h, st), ubc = lax.scan(body, (h, st), (xs, cache["blocks"]))
         new_cache["blocks"] = {
             f"sub_{s}": _apply_updates(
                 cfg, cfg.layer_pattern[s], cache["blocks"][f"sub_{s}"],
                 ubc[f"sub_{s}"], positions, axis=1, mesh=mesh)
             for s in range(period)}
 
-    if n_tail:
-        nt = {}
-        for j in range(n_tail):
-            kind = cfg.layer_kind(G * period + j)
-            c = cache["tail"][f"block_{j}"]
-            h, upd = decode_block(cfg, params["tail"][f"block_{j}"], h, c,
-                                  kind, positions=positions, mesh=mesh)
-            nt[f"block_{j}"] = _apply_updates(cfg, kind, c, upd, positions,
-                                              axis=0, mesh=mesh)
-        new_cache["tail"] = nt
+    h, st = unrolled(h, st, "tail", [
+        _kind(cfg, G, period, j) for j in range(n_tail)])
 
     h = L.apply_norm(cfg, params["final_norm"], h)
     logits = lm_logits(cfg, params, h, mesh=mesh)
+    if stats:
+        return logits, new_cache, st
     return logits, new_cache
 
 
@@ -461,6 +558,8 @@ def _block_cache_zeros(cfg: ModelConfig, kind: str, B: int, cache_len: int,
                        cross: bool):
     dt = L._dt(cfg)
     F = cfg.num_kv_heads * cfg.head_dim      # kv heads side by side
+    if kind in ("global", "local") and cfg.mla:
+        return {"ckv": jnp.zeros((B, cache_len, cfg.cache_width), dt)}
     if kind in ("global", "local"):
         if L.ring_cache(cfg, kind):
             W = min(cfg.sliding_window, cache_len)
@@ -488,6 +587,11 @@ def init_cache(cfg: ModelConfig, B: int, cache_len: int) -> Params:
     period, G, n_tail = _layout(cfg)
     cross = cfg.encoder_layers > 0
     cache: Params = {}
+    if cfg.first_dense_layers:
+        cache["dense"] = {
+            f"block_{j}": _block_cache_zeros(cfg, cfg.layer_kind(j), B,
+                                             cache_len, cross)
+            for j in range(cfg.first_dense_layers)}
     if G:
         def one(_):
             return {f"sub_{s}": _block_cache_zeros(
@@ -497,6 +601,6 @@ def init_cache(cfg: ModelConfig, B: int, cache_len: int) -> Params:
     if n_tail:
         cache["tail"] = {
             f"block_{j}": _block_cache_zeros(
-                cfg, cfg.layer_kind(G * period + j), B, cache_len, cross)
+                cfg, _kind(cfg, G, period, j), B, cache_len, cross)
             for j in range(n_tail)}
     return cache

@@ -24,25 +24,51 @@ def serving_config(cfg: ModelConfig) -> ModelConfig:
     return cfg.replace(param_dtype=cfg.dtype)
 
 
+EXPERT_STATS = ("expert_rows", "experts_hit", "expert_drops")
+
+# jitted single-device steps, kept for the process (``BatchedServer``)
+_STEPS: dict = {}
+
+
 def build_prefill_step(cfg: ModelConfig, mesh=None,
                        cache_len: Optional[int] = None):
+    """Prefill: the last position's logits and, with ``cache_len``, the
+    decode cache; for a config with experts also ``moe``, int32
+    [``EXPERT_STATS``] summed over layers (``layers.moe_layer``)."""
     def prefill_step(params, batch):
         out = forward(cfg, params, batch["tokens"],
                       vision_embeds=batch.get("vision_embeds"),
                       enc_frames=batch.get("enc_frames"),
                       mesh=mesh, cache_len=cache_len, batch_kind="serve")
         logits = lm_logits(cfg, params, out["h"][:, -1:], mesh=mesh)
-        if cache_len is None:
-            return {"logits": logits}
-        return {"logits": logits, "cache": out["cache"]}
+        res = {"logits": logits}
+        if cache_len is not None:
+            res["cache"] = out["cache"]
+        if cfg.num_experts:
+            res["moe"] = out["moe"]
+        return res
     return prefill_step
 
 
 def build_serve_step(cfg: ModelConfig, mesh=None):
-    """decode: one new token for the whole batch against the cache."""
+    """decode: one new token for the whole batch against the cache;
+    (logits, cache), and for a config with experts also its ``moe``
+    counts as ``build_prefill_step``'s."""
     def serve_step(params, cache, tokens, positions):
-        return decode_step(cfg, params, cache, tokens, positions, mesh=mesh)
+        return decode_step(cfg, params, cache, tokens, positions, mesh=mesh,
+                           stats=bool(cfg.num_experts))
     return serve_step
+
+
+def step_out_shardings(cfg: ModelConfig, cache_sh):
+    """out_shardings of the prefill and the serve step with the cache
+    pinned to ``cache_sh``: logits and any expert counts replicated."""
+    prefill = {"logits": None, "cache": cache_sh}
+    serve = (None, cache_sh)
+    if cfg.num_experts:
+        prefill["moe"] = None
+        serve += (None,)
+    return prefill, serve
 
 
 def cache_specs(cfg: ModelConfig, B: int, cache_len: int):
@@ -115,7 +141,11 @@ class BatchedServer:
     bookkeeping; ``cache_donated``: whether the cache passed in was
     consumed, i.e. every leaf deleted), ``engine.wait`` (each blocking
     read-back, inside those two) and ``engine.request`` (submit to
-    eviction, per ``rid``).
+    eviction, per ``rid``).  For a config with experts, ``engine.admit``
+    and ``engine.decode`` carry the step's ``EXPERT_STATS`` (rows the
+    held experts computed, held experts with a row, rows dropped; summed
+    over layers), read back with the step's tokens, and ``stats`` sums
+    them over the run.
     """
 
     def __init__(self, cfg: ModelConfig, params, *, batch: int, prompt_len: int,
@@ -141,22 +171,29 @@ class BatchedServer:
             p_sh = state_shardings(cfg, mesh, params)
             c_sh = cache_shardings(cfg, mesh,
                                    cache_specs(cfg, batch, max_len))
+            pre_sh, step_sh = step_out_shardings(cfg, c_sh)
             self.prefill = jax.jit(
                 build_prefill_step(cfg, mesh, cache_len=max_len),
-                in_shardings=(p_sh, None),
-                out_shardings={"logits": None, "cache": c_sh})
+                in_shardings=(p_sh, None), out_shardings=pre_sh)
             self.step = jax.jit(
                 build_serve_step(cfg, mesh),
                 in_shardings=(p_sh, c_sh, None, None),
-                out_shardings=(None, c_sh), donate_argnums=(1,))
+                out_shardings=step_sh, donate_argnums=(1,))
         else:
-            self.prefill = jax.jit(
-                build_prefill_step(cfg, mesh, cache_len=max_len))
-            self.step = jax.jit(build_serve_step(cfg, mesh),
-                                donate_argnums=(1,))
+            # one pair of jitted steps per (builders, config, cache length)
+            # for the process: a server per traffic window reuses them
+            # instead of tracing both steps again
+            key = (build_prefill_step, build_serve_step, cfg, max_len)
+            if key not in _STEPS:
+                _STEPS[key] = (
+                    jax.jit(build_prefill_step(cfg, cache_len=max_len)),
+                    jax.jit(build_serve_step(cfg), donate_argnums=(1,)))
+            self.prefill, self.step = _STEPS[key]
         self.queue: collections.deque = collections.deque()
         self.stats = {"served": 0, "decode_steps": 0, "prefills": 0,
                       "slot_steps": 0}
+        if cfg.num_experts:
+            self.stats.update(dict.fromkeys(EXPERT_STATS, 0))
         if tracer is not None:
             tracer.close(build)
 
@@ -201,18 +238,15 @@ class BatchedServer:
             cache = fresh
         else:
             mask = jnp.asarray([i in joinset for i in range(self.B)])
-            merged = {}
-            if "blocks" in cache:      # scanned: leaves (G, B, ...)
-                merged["blocks"] = _merge_rows(
-                    cache["blocks"], fresh["blocks"], mask, axis=1)
-            if "tail" in cache:        # unscanned: leaves (B, ...)
-                merged["tail"] = _merge_rows(
-                    cache["tail"], fresh["tail"], mask, axis=0)
-            cache = merged
+            # the scanned stack's leaves are (G, B, ...), the unrolled
+            # layers' (``dense``, ``tail``) (B, ...)
+            cache = {k: _merge_rows(cache[k], fresh[k], mask,
+                                    axis=1 if k == "blocks" else 0)
+                     for k in cache}
         first = jnp.argmax(out["logits"][:, 0], axis=-1)
         if tr is not None:
             wait = tr.open("engine", "wait")
-        first = jax.device_get(first)
+        first, moe = jax.device_get((first, out.get("moe")))
         if tr is not None:
             tr.close(wait)
         now = self.clock()
@@ -220,9 +254,20 @@ class BatchedServer:
             last[i] = int(first[i])
             positions[i] = self.S0
             slots[i].first_token_at = now
+        counts = self._count_experts(moe)
         if tr is not None:
-            tr.close(admit)
+            tr.close(admit, **counts)
         return cache
+
+    def _count_experts(self, moe) -> dict:
+        """A step's expert counts as span attributes, added to ``stats``
+        (none for a config without experts)."""
+        if moe is None:
+            return {}
+        counts = {k: int(v) for k, v in zip(EXPERT_STATS, moe)}
+        for k, v in counts.items():
+            self.stats[k] += v
+        return counts
 
     def _run_continuous(self) -> List[Request]:
         done: List[Request] = []
@@ -238,16 +283,17 @@ class BatchedServer:
                     s is not None for s in slots))
                 evicted = len(done)
                 passed = cache
-            logits, cache = self.step(
+            logits, cache, *moe = self.step(
                 self.params, cache, jnp.asarray(last, jnp.int32)[:, None],
                 jnp.asarray(positions, jnp.int32))
             self.stats["decode_steps"] += 1
             nxt = jnp.argmax(logits[:, 0], axis=-1)
             if tr is not None:
                 wait = tr.open("engine", "wait")
-            nxt = jax.device_get(nxt)
+            nxt, moe = jax.device_get((nxt, moe[0] if moe else None))
             if tr is not None:
                 tr.close(wait)
+            counts = self._count_experts(moe)
             for i, r in enumerate(slots):
                 if r is None:
                     continue
@@ -261,7 +307,7 @@ class BatchedServer:
                     self.stats["served"] += 1
                     slots[i] = None      # evict: slot free next admission
             if tr is not None:
-                tr.close(step, cache_donated=_deleted(passed))
+                tr.close(step, cache_donated=_deleted(passed), **counts)
                 for r in done[evicted:]:
                     tr.record("engine", "request", r.submitted_at,
                               r.done_at, rid=r.rid,
@@ -284,8 +330,8 @@ class BatchedServer:
             nsteps = max(r.max_new_tokens for r in wave)
             for t in range(nsteps):
                 pos = jnp.full((self.B,), self.S0 + t, jnp.int32)
-                logits, cache = self.step(self.params, cache,
-                                          last[:, None], pos)
+                logits, cache, *_ = self.step(self.params, cache,
+                                              last[:, None], pos)
                 last = jnp.argmax(logits[:, 0], axis=-1)
                 self.stats["decode_steps"] += 1
                 host = jax.device_get(last)

@@ -120,7 +120,8 @@ def test_moe_no_drop_matches_dense_reference():
     key = jax.random.PRNGKey(0)
     p = L.init_moe(cfg, key)
     x = 0.1 * jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model))
-    y, aux = L.apply_moe(cfg, p, x, capacity_factor=float(cfg.num_experts))
+    y, aux, _ = L.moe_layer(cfg.replace(moe_capacity=float(cfg.num_experts)),
+                            p, x)
 
     # dense reference: run every expert on all tokens, weight top-k
     xt = x.reshape(-1, cfg.d_model)

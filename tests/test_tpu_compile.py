@@ -72,11 +72,30 @@ def _kernel_case(name):
         E, D, F = c.num_experts, c.d_model, c.expert_ff
         fn = lambda x, w, s: gmm(x, w, s, impl="pallas")
         return fn, [((E, 128, D), bf), ((E, D, F), bf), ((E,), jnp.int32)]
+    if name == "moe_gmm_dropless":           # moonlight: 8 held experts,
+        c = get_config("moonlight-16b-a3b")  # a row per token of a prefill
+        D, F = c.d_model, c.expert_ff
+        fn = lambda x, w, s: gmm(x, w, s, impl="pallas")
+        return fn, [((8, 4096, D), bf), ((8, D, F), bf), ((8,), jnp.int32)]
+    if name == "moe_gmm_stacked":            # moonlight decode: the layer
+        c = get_config("moonlight-16b-a3b")  # scan's whole expert stack
+        L, D, F = c.num_layers - 1, c.d_model, c.expert_ff
+        fn = lambda x, w, s, i: gmm(x, w, s, i, impl="pallas")
+        return fn, [((8, 16, D), bf), ((L, 8, D, F), bf), ((8,), jnp.int32),
+                    ((2,), jnp.int32)]
+    if name == "flash_attention_mla":        # moonlight prefill, expanded
+        c = get_config("moonlight-16b-a3b")
+        S, H = 2048, c.num_heads
+        fn = lambda q, k, v: flash_attention(
+            q, k, v, scale=c.head_dim ** -0.5, impl="pallas")
+        return fn, [((1, S, H, c.head_dim), bf), ((1, S, H, c.head_dim), bf),
+                    ((1, S, H, c.v_head_dim), bf)]
     raise ValueError(name)
 
 
 @pytest.mark.parametrize("name", ["flash_attention", "rglru", "mamba",
-                                  "moe_gmm"])
+                                  "moe_gmm", "moe_gmm_dropless",
+                                  "moe_gmm_stacked", "flash_attention_mla"])
 def test_pallas_kernel_compiles_for_v5e(name, one_chip):
     fn, args = _kernel_case(name)
     specs = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
