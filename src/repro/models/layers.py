@@ -10,18 +10,20 @@ Conventions:
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from repro import flags
 from repro.configs.base import ModelConfig
 from repro.dist.sharding import cache_shardings
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.mamba.ops import selective_scan, selective_step
-from repro.kernels.moe_gmm.ops import gmm
+from repro.kernels.moe_gmm.ops import gmm, gmm_ragged
+from repro.kernels.moe_gmm.ref import BLOCK_ROWS
 from repro.kernels.rglru.ops import linear_scan
 
 Params = Dict[str, Any]
@@ -440,10 +442,10 @@ def _round_up(x: int, m: int) -> int:
 
 ROUTER_BIAS_STD = 0.1   # e_score_correction_bias drawn from the seed
 
-# rows of one expert pass of the held-expert layout: each expert's buffer
-# holds ``capacity`` rows, so a prefill of many tokens runs its experts a
-# few at a time (one per pass at 32k tokens) to bound the transient buffers
-EXPERT_PASS_ROWS = 32_768
+# rows of one chunk of the ragged expert layout: a dropless layer whose
+# capacity layout would hold more rows than this lays its routed rows out
+# ragged and runs them a chunk at a time
+EXPERT_CHUNK_ROWS = 4096
 
 
 def init_moe(cfg: ModelConfig, key) -> Params:
@@ -504,58 +506,89 @@ def route(cfg: ModelConfig, p: Params, xt):
     return wts, idx, aux
 
 
-def _expert_pass(E_local: int, C: int) -> int:
-    """Experts per pass: the most that divide ``E_local`` and keep a pass
-    within ``EXPERT_PASS_ROWS`` rows (at least one)."""
-    g = max(1, min(E_local, EXPERT_PASS_ROWS // max(C, 1)))
-    while E_local % g:
-        g -= 1
-    return g
+class _Capacity(NamedTuple):
+    """Each held expert's rows in a buffer of C: ``tok`` (E_local, C) the
+    rows' token ids (``T`` for an empty row, which reads a zero row),
+    ``sizes`` (E_local,) each expert's row count (the grouped matmul skips
+    the row blocks past it), ``pos`` (E_local, T) each token's row in each
+    expert's list (``C`` where it has none) and ``wt`` (E_local, T) its
+    routing weight there (0 where it has none)."""
+    tok: Any
+    sizes: Any
+    pos: Any
+    wt: Any
 
 
-def _expert_ffn(cfg: ModelConfig, p: Params, xt, tok, sizes, pos, wt):
-    """The held experts' weighted sum for each token.  ``tok`` (E_local, C)
-    lists each expert's rows by token id (``T`` for an empty row, which
-    reads a zero row), ``sizes`` (E_local,) each expert's row count: the
-    grouped matmul skips the row blocks past it.  ``pos`` (E_local, T) is
-    each token's row in each expert's list (``C`` where it has none) and
-    ``wt`` (E_local, T) its routing weight there (0 where it has none):
-    each token gathers its rows back, so no scatter of the mostly empty
-    rows.  The expert weights are this layer's (E_local, D, F), or the
-    whole stack (layers, E_local, D, F) with ``p["layer"]`` its index
-    (``transformer.scan_stack``); either way the kernel is handed the
-    array and an index, never a slice.  Returns (T, D) f32."""
+class _Ragged(NamedTuple):
+    """The held experts' rows one group after another, each group padded
+    to a multiple of ``BLOCK_ROWS``: ``tok`` (N,) each row's token id (``T``
+    for a padding row), ``wt`` (N,) its routing weight (0 for padding),
+    ``experts`` (N / BLOCK_ROWS,) each row block's held expert, ``live``
+    the blocks that hold rows and ``chunks`` the ``EXPERT_CHUNK_ROWS``
+    chunks they fill."""
+    tok: Any
+    wt: Any
+    experts: Any
+    live: Any
+    chunks: Any
+
+
+def _ragged(cfg: ModelConfig, E_local: int, C: int,
+            capacity_factor: float) -> bool:
+    """Whether the layer lays its held experts' rows out ragged: dropless,
+    where the capacity layout would hold more than a chunk of rows, under
+    the forward-only (Pallas) expert kernel.  The chunk loop runs as many
+    times as the routing fills chunks, which reverse mode cannot
+    differentiate: the differentiable XLA path keeps the capacity
+    layout."""
+    return (not capacity_factor and E_local * C > EXPERT_CHUNK_ROWS
+            and flags.moe_impl(_impl(cfg)) in ("pallas", "pallas_interpret"))
+
+
+def _expert_ffn(cfg: ModelConfig, p: Params, xt, rows):
+    """The held experts' weighted sum for each token, over ``rows``, a
+    ``_Capacity`` or ``_Ragged`` layout.  Capacity: each token gathers its
+    rows back, so no scatter of the mostly empty rows.  Ragged: a loop
+    over the chunks the rows fill gathers each chunk's token rows and adds
+    its weighted outputs into the tokens' sums.  The expert weights are
+    this layer's (E_local, D, F), or the whole stack (layers, E_local, D,
+    F) with ``p["layer"]`` its index (``transformer.scan_stack``); either
+    way the kernel is handed the array and an index, never a slice.
+    Returns (T, D) f32."""
     T, D = xt.shape
-    E_local, C = tok.shape
     xpad = jnp.concatenate([xt, jnp.zeros((1, D), xt.dtype)])
-    layer = p.get("layer", 0)
+    idx = jnp.stack([p.get("layer", 0), 0]).astype(jnp.int32)
     ws = {k: cast(cfg, p[k] if "layer" in p else p[k][None])
           for k in ("wi", "wg", "wo") if k in p}
 
-    def one(y, xs):
-        tok_g, sz_g, e0, pos_g, wt_g = xs
-        g = tok_g.shape[0]
-        idx = jnp.stack([layer, e0]).astype(jnp.int32)
-        xe = xpad[tok_g]                                    # (g, C, D)
-        hi = gmm(xe, ws["wi"], sz_g, idx, impl=_impl(cfg))
-        hg = gmm(xe, ws["wg"], sz_g, idx, impl=_impl(cfg)) \
-            if "wg" in ws else None
-        ye = gmm(_mlp_act(cfg, hi, hg), ws["wo"], sz_g, idx, impl=_impl(cfg))
-        # a token without a row in an expert reads its last row, at weight 0
-        rows = ye[jnp.arange(g)[:, None], jnp.minimum(pos_g, C - 1)]
-        return y + jnp.sum(wt_g[..., None] * rows.astype(jnp.float32),
-                           axis=0), None
+    def ffn(x, matmul):
+        hi = matmul(x, ws["wi"])
+        hg = matmul(x, ws["wg"]) if "wg" in ws else None
+        return matmul(_mlp_act(cfg, hi, hg), ws["wo"])
 
-    y = jnp.zeros((T, D), jnp.float32)
-    g = _expert_pass(E_local, C)
-    if g == E_local:
-        y, _ = one(y, (tok, sizes, 0, pos, wt))
-    else:
-        split = lambda a: a.reshape((E_local // g, g) + a.shape[1:])  # noqa: E731
-        y, _ = lax.scan(one, y, (split(tok), split(sizes),
-                                 jnp.arange(0, E_local, g), split(pos),
-                                 split(wt)))
-    return y
+    if isinstance(rows, _Capacity):
+        E_local, C = rows.tok.shape
+        ye = ffn(xpad[rows.tok], lambda x, w: gmm(
+            x, w, rows.sizes, idx, impl=_impl(cfg)))       # (E_local, C, D)
+        # a token without a row in an expert reads its last row, at weight 0
+        back = ye[jnp.arange(E_local)[:, None], jnp.minimum(rows.pos, C - 1)]
+        return jnp.sum(rows.wt[..., None] * back.astype(jnp.float32), axis=0)
+
+    R, nb = EXPERT_CHUNK_ROWS, EXPERT_CHUNK_ROWS // BLOCK_ROWS
+
+    def chunk(c, y):
+        tok = lax.dynamic_slice_in_dim(rows.tok, c * R, R)
+        wt = lax.dynamic_slice_in_dim(rows.wt, c * R, R)
+        experts = lax.dynamic_slice_in_dim(rows.experts, c * nb, nb)
+        live = jnp.clip(rows.live - c * nb, 0, nb)
+        ye = ffn(xpad[tok], lambda x, w: gmm_ragged(
+            x, w, experts, live, idx, impl=_impl(cfg)))    # (R, D)
+        # padding rows add nothing, into the dummy row T
+        return y.at[tok].add(wt[:, None] * ye.astype(jnp.float32))
+
+    y = lax.fori_loop(0, rows.chunks, chunk,
+                      jnp.zeros((T + 1, D), jnp.float32))
+    return y[:T]
 
 
 def _moe_local(cfg: ModelConfig, p: Params, xt, e_base, E_local: int,
@@ -568,10 +601,13 @@ def _moe_local(cfg: ModelConfig, p: Params, xt, e_base, E_local: int,
     may be traced (lax.axis_index).  Each expert takes at most
     ``capacity`` rows: ``capacity_factor`` times its even share, or, at
     ``capacity_factor`` 0 (dropless), all T tokens, which no routing can
-    exceed (a token picks an expert once).  Returns (y (T, D) partial sum
-    over local experts, aux load-balance loss over the full expert
-    population, int32 [rows the local experts computed, local experts
-    with a row, rows routed here but dropped past capacity]).
+    exceed (a token picks an expert once).  A dropless layer whose
+    capacity layout would waste rows lays them out ragged (``_ragged``).
+    Returns (y (T, D) partial sum over local experts, aux load-balance
+    loss over the full expert population, int32 [rows the local experts
+    computed, local experts with a row, rows routed here but dropped past
+    capacity, rows of the layout the grouped matmuls ran: E_local * C, or
+    the ragged chunks' rows]).
     """
     T, D = xt.shape
     E, k = cfg.num_experts, cfg.experts_per_tok
@@ -600,29 +636,58 @@ def _moe_local(cfg: ModelConfig, p: Params, xt, e_base, E_local: int,
             int(math.ceil(T * k / E * capacity_factor)), cap_block))
     else:
         C = _round_up(T, 128 if T >= 128 else 8)
-    keep = (pos_in_e < C) & (el_s < E_local)
-    slot = jnp.where(keep, el_s * C + pos_in_e, E_local * C)
 
-    tok = jnp.full((E_local * C + 1,), T, jnp.int32).at[slot].set(tid_s)
-    # each token's row and weight in each held expert, for the gather back
-    e_k = jnp.where(keep, el_s, E_local)
-    t_k = jnp.where(keep, tid_s, T)
-    pos = jnp.full((E_local + 1, T + 1), C, jnp.int32).at[e_k, t_k].set(
-        pos_in_e)
-    wt = jnp.zeros((E_local + 1, T + 1), jnp.float32).at[e_k, t_k].set(w_s)
-    sizes = jnp.minimum(counts[:E_local], C)
-    y = _expert_ffn(cfg, p, xt, tok[:-1].reshape(E_local, C), sizes,
-                    pos[:E_local, :T], wt[:E_local, :T])
-    rows = jnp.sum(sizes)
-    stats = jnp.stack([rows, jnp.sum(sizes > 0),
-                       jnp.sum(counts[:E_local]) - rows]).astype(jnp.int32)
+    if _ragged(cfg, E_local, C, capacity_factor):
+        # groups padded to whole row blocks; every routed row fits in the
+        # bound, however skewed the routing (a token picks an expert once)
+        padded = (counts[:E_local] + BLOCK_ROWS - 1) // BLOCK_ROWS \
+            * BLOCK_ROWS
+        ends = jnp.cumsum(padded)
+        N = _round_up(T * min(k, E_local) + E_local * (BLOCK_ROWS - 1),
+                      EXPERT_CHUNK_ROWS)
+        row = jnp.where(el_s < E_local,
+                        (ends - padded)[jnp.minimum(el_s, E_local - 1)]
+                        + pos_in_e, N)
+        live = ends[-1] // BLOCK_ROWS
+        blocks = jnp.arange(N // BLOCK_ROWS) * BLOCK_ROWS
+        rows = _Ragged(
+            tok=jnp.full((N + 1,), T, jnp.int32).at[row].set(tid_s)[:N],
+            wt=jnp.zeros((N + 1,), jnp.float32).at[row].set(w_s)[:N],
+            experts=jnp.minimum(jnp.searchsorted(ends, blocks, side="right"),
+                                E_local - 1).astype(jnp.int32),
+            live=live,
+            chunks=-(-live // (EXPERT_CHUNK_ROWS // BLOCK_ROWS)))
+        sizes = counts[:E_local]
+        slots = rows.chunks * EXPERT_CHUNK_ROWS
+    else:
+        keep = (pos_in_e < C) & (el_s < E_local)
+        slot = jnp.where(keep, el_s * C + pos_in_e, E_local * C)
+        tok = jnp.full((E_local * C + 1,), T, jnp.int32).at[slot].set(tid_s)
+        # each token's row and weight in each held expert, for the gather
+        # back
+        e_k = jnp.where(keep, el_s, E_local)
+        t_k = jnp.where(keep, tid_s, T)
+        pos = jnp.full((E_local + 1, T + 1), C, jnp.int32).at[e_k, t_k].set(
+            pos_in_e)
+        wt = jnp.zeros((E_local + 1, T + 1), jnp.float32).at[e_k, t_k].set(
+            w_s)
+        sizes = jnp.minimum(counts[:E_local], C)
+        rows = _Capacity(tok[:-1].reshape(E_local, C), sizes,
+                         pos[:E_local, :T], wt[:E_local, :T])
+        slots = E_local * C
+    y = _expert_ffn(cfg, p, xt, rows)
+    computed = jnp.sum(sizes)
+    stats = jnp.stack([computed, jnp.sum(sizes > 0),
+                       jnp.sum(counts[:E_local]) - computed,
+                       slots]).astype(jnp.int32)
     return y.astype(xt.dtype), aux, stats
 
 
 def moe_layer(cfg: ModelConfig, p: Params, x, *, mesh=None):
     """The expert MLP: the held (or local) experts' part of the routed sum,
     plus the shared experts whole.  Returns (y, aux_loss, stats): ``stats``
-    int32 [expert rows computed, held experts with a row, rows dropped].
+    int32 [expert rows computed, held experts with a row, rows dropped,
+    rows of the expert layout run] (``_moe_local``).
     EP via shard_map when mesh has a 'model' axis and the profile is
     tp_ep; otherwise dispatch is local per data shard (expert weights
     TP-sharded by GSPMD for the grok-style profile).  A rank holding a

@@ -158,7 +158,7 @@ def init_params(cfg: ModelConfig, key) -> Params:
 # ---------------------------------------------------------------- blocks
 
 def _no_stats():
-    return jnp.zeros((3,), jnp.int32)
+    return jnp.zeros((4,), jnp.int32)
 
 
 def forward_block(cfg: ModelConfig, bp: Params, h, kind: str, *, positions,

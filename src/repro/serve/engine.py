@@ -24,7 +24,8 @@ def serving_config(cfg: ModelConfig) -> ModelConfig:
     return cfg.replace(param_dtype=cfg.dtype)
 
 
-EXPERT_STATS = ("expert_rows", "experts_hit", "expert_drops")
+EXPERT_STATS = ("expert_rows", "experts_hit", "expert_drops",
+                "expert_row_slots")
 
 # jitted single-device steps, kept for the process (``BatchedServer``)
 _STEPS: dict = {}
@@ -143,9 +144,9 @@ class BatchedServer:
     read-back, inside those two) and ``engine.request`` (submit to
     eviction, per ``rid``).  For a config with experts, ``engine.admit``
     and ``engine.decode`` carry the step's ``EXPERT_STATS`` (rows the
-    held experts computed, held experts with a row, rows dropped; summed
-    over layers), read back with the step's tokens, and ``stats`` sums
-    them over the run.
+    held experts computed, held experts with a row, rows dropped, rows of
+    the expert layout the grouped matmuls ran; summed over layers), read
+    back with the step's tokens, and ``stats`` sums them over the run.
     """
 
     def __init__(self, cfg: ModelConfig, params, *, batch: int, prompt_len: int,

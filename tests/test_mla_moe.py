@@ -147,7 +147,7 @@ def test_dropless_under_bias_that_sends_every_token_to_one_expert():
     p = _moe_params(cfg)
     p["bias"] = p["bias"].at[1].set(100.0)
     y, _, stats = L.moe_layer(cfg, p, x)
-    rows, hit, drops = (int(v) for v in stats)
+    rows, hit, drops, _ = (int(v) for v in stats)
     assert drops == 0 and rows >= T
     np.testing.assert_allclose(y, ref.experts(cfg, p, x, range(4)),
                                atol=TOL, rtol=0)
@@ -184,11 +184,16 @@ def test_engine_records_expert_counts_on_its_spans():
     spans = [s for s in tr.spans if s["cat"] == "engine"
              and s["name"] in ("decode", "admit")]
     assert {s["name"] for s in spans} == {"decode", "admit"}
+    moe_layers = cfg.num_layers - cfg.first_dense_layers
     for s in spans:
         assert all(k in s for k in EXPERT_STATS), s
         assert s["expert_drops"] == 0 and s["expert_rows"] > 0
-        assert 0 < s["experts_hit"] <= cfg.held_experts * (
-            cfg.num_layers - cfg.first_dense_layers)
+        assert 0 < s["experts_hit"] <= cfg.held_experts * moe_layers
+        # the capacity layout, C rows for each held expert: C the step's
+        # tokens (2 slots, or 2 x 8 prompt tokens) rounded up to 8
+        C = 8 if s["name"] == "decode" else 16
+        assert s["expert_row_slots"] == cfg.held_experts * C * moe_layers
+        assert s["expert_rows"] <= s["expert_row_slots"]
     for k in EXPERT_STATS:
         assert srv.stats[k] == sum(s[k] for s in spans)
     dec = [s for s in spans if s["name"] == "decode"]

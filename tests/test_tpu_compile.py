@@ -17,7 +17,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.mamba.ops import selective_scan
-from repro.kernels.moe_gmm.ops import gmm
+from repro.kernels.moe_gmm.ops import gmm, gmm_ragged
 from repro.kernels.rglru.ops import linear_scan
 
 
@@ -83,6 +83,14 @@ def _kernel_case(name):
         fn = lambda x, w, s, i: gmm(x, w, s, i, impl="pallas")
         return fn, [((8, 16, D), bf), ((L, 8, D, F), bf), ((8,), jnp.int32),
                     ((2,), jnp.int32)]
+    if name in ("moe_gmm_ragged", "moe_gmm_ragged_down"):
+        c = get_config("moonlight-16b-a3b")  # moonlight prefill: a chunk of
+        L, D, F = c.num_layers - 1, c.d_model, c.expert_ff  # ragged rows
+        if name.endswith("down"):
+            D, F = F, D
+        fn = lambda x, w, be, n, i: gmm_ragged(x, w, be, n, i, impl="pallas")
+        return fn, [((4096, D), bf), ((L, 8, D, F), bf), ((32,), jnp.int32),
+                    ((), jnp.int32), ((2,), jnp.int32)]
     if name == "flash_attention_mla":        # moonlight prefill, expanded
         c = get_config("moonlight-16b-a3b")
         S, H = 2048, c.num_heads
@@ -95,7 +103,9 @@ def _kernel_case(name):
 
 @pytest.mark.parametrize("name", ["flash_attention", "rglru", "mamba",
                                   "moe_gmm", "moe_gmm_dropless",
-                                  "moe_gmm_stacked", "flash_attention_mla"])
+                                  "moe_gmm_stacked", "moe_gmm_ragged",
+                                  "moe_gmm_ragged_down",
+                                  "flash_attention_mla"])
 def test_pallas_kernel_compiles_for_v5e(name, one_chip):
     fn, args = _kernel_case(name)
     specs = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
