@@ -168,7 +168,6 @@ class Fleet:
                 rt.staging.planner.locality = rt.staging.locality
         else:
             rt._pod_prefix = prefix
-        rt._fleet_name = name
         if rt.journal.tag is None:
             rt.journal.tag = name
         self.pilots[name] = rt

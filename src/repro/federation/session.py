@@ -1,10 +1,11 @@
 """FederatedSession: late-binding dispatch of one task stream onto N pilots.
 
 Subclasses :class:`RuntimeSession` and overrides exactly the dispatch
-hooks the base exposes (``_rt_for``, ``_occupy``, ``_can_launch_real``,
-``_fault_source``, ...) so the drain loops — DES event loop, real-mode
-condition-variable loop, fault scans, zombie guards, speculation plumbing —
-run UNCHANGED.  What federation adds:
+hooks the base exposes (``_rt_for``, ``_occupy``/``_vacate``,
+``_can_launch_real``, ``_too_wide``, ``_fault_source``, ``_housekeeping``,
+``_preempt_for``, ...) so the drain loops — DES event loop, real-mode
+condition-variable loop — and the policies they share (pod kill, abandon,
+eviction, zombie guards) run UNCHANGED.  What federation adds:
 
 * **Late binding**: a task is bound to a pilot at LAUNCH time, not submit
   time.  ``_dispatch`` scores every pilot with free capacity and picks the
@@ -12,27 +13,27 @@ run UNCHANGED.  What federation adds:
   task's staged inputs there (0 for a pilot already holding a replica,
   ``cross_gbps`` for a pilot-to-pilot fetch, ``host_gbps`` from HOST),
   tie-broken by load, with blamed pilots (retry exclusion) last.
-* **Per-pilot capacity accounts** (``_busy_by``/``_free_by``) beside the
-  base session's global ones — dispatch feasibility is per pilot; a
-  32-slot fleet of 4 pilots cannot host a 16-wide task.
+* **A per-pilot busy account** (``_busy_by``) beside the base session's
+  global one; a pilot's free capacity is ``rt.slots - _busy_by[name]`` on
+  both clocks.  Dispatch feasibility is per pilot: a 32-slot fleet of 4
+  pilots cannot host a 16-wide task.
 * **Per-pilot journals**: ``session_start`` is written into EVERY pilot's
   journal (tagged with the pilot name) and replay at construction merges
   every pilot's ``load_state()`` — a crashed federated run reconstructs
   the whole fleet's progress from the per-pilot files.
 * **Whole-pilot death** reuses the pod-failure machinery verbatim: each
   pod of the dead pilot is abandoned/retired/replica-dropped by the
-  existing kill paths (pods carry their pilot's prefix, so routing is a
+  existing kill path (pods carry their pilot's prefix, so routing is a
   name parse), the pilot bottoms out at 0 slots and stops receiving
   dispatches, and retries late-bind onto survivors.
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional
 
 from repro.federation.fleet import Fleet, _FaultUnion
 from repro.runtime.executor import PilotRuntime, RuntimeSession
-from repro.runtime.states import Task, TaskState
+from repro.runtime.states import Task
 from repro.staging.store import HOST
 
 
@@ -41,7 +42,6 @@ class FederatedSession(RuntimeSession):
         self.fleet = fleet
         super().__init__(fleet, graph=graph, on_task_done=on_task_done)
         self._busy_by: Dict[str, int] = {}
-        self._free_by: Dict[str, int] = {}
         self._started: set = set()
         self._init_done = False
         for name, rt in fleet.pilots.items():
@@ -50,7 +50,7 @@ class FederatedSession(RuntimeSession):
 
     def _start_pilot(self, name: str, rt: PilotRuntime):
         """Open one pilot for dispatch: merge its journal's replay state
-        into the session's, init its capacity accounts, and mark a new
+        into the session's, init its busy account, and mark a new
         session segment in ITS journal (tagged — the sanitizer resets
         only this pilot's epoch state)."""
         done, results, history = rt.journal.load_state()
@@ -59,9 +59,6 @@ class FederatedSession(RuntimeSession):
         for task, entries in history.items():
             self._replayed_history.setdefault(task, entries)
         self._busy_by[name] = 0
-        self._free_by[name] = rt.slots
-        if self._init_done and self.fleet.mode == "real":
-            self._free["n"] += rt.slots     # joined mid-session: new capacity
         self._started.add(name)
         if self.fleet.mode == "sim":
             # per-pilot journals are time-faithful too: every record this
@@ -83,27 +80,21 @@ class FederatedSession(RuntimeSession):
                 self._start_pilot(name, rt)
 
     def on_pilot_retired(self, name: str):
-        """Recruiter shrink notification: the pilot's free capacity
-        leaves the global real-mode account (its per-pilot account zeroes
-        so a later revival cannot double-credit)."""
-        if self.fleet.mode == "real":
-            self._free["n"] -= max(self._free_by.get(name, 0), 0)
-        self._free_by[name] = 0
+        """Recruiter shrink notification.  The pilot runs nothing (the
+        recruiter retires only idle pilots) and leaves ``fleet.active()``,
+        so its capacity leaves dispatch and the fleet's ``slots``."""
         if self.tracer is not None:
             self.tracer.instant("pilot", f"retire:{name}", self._now(),
                                 pilot=name)
 
     def pilot_busy(self, name: str) -> int:
-        if self.fleet.mode == "sim":
-            return self._busy_by.get(name, 0)
-        rt = self.fleet.pilots[name]
-        return max(rt.slots - self._free_by.get(name, 0), 0)
+        return self._busy_by.get(name, 0)
 
-    @property
-    def busy_slots(self) -> int:
-        if self.fleet.mode == "sim":
-            return self._busy
-        return sum(self.pilot_busy(n) for n in self.fleet.active())
+    def _pilot_free(self) -> Dict[str, int]:
+        """Free slots of every active pilot, derived from its busy
+        account."""
+        return {n: rt.slots - self._busy_by.get(n, 0)
+                for n, rt in self.fleet.active().items()}
 
     # ------------------------------------------------------- dispatch hooks
     def _rt_for(self, t: Task) -> PilotRuntime:
@@ -126,26 +117,8 @@ class FederatedSession(RuntimeSession):
         if name in self._busy_by:
             self._busy_by[name] -= t.slots
 
-    def _debit_free(self, t: Task):
-        self._free["n"] -= t.slots
-        name = t.meta.get("pilot")
-        if name in self._free_by:
-            self._free_by[name] -= t.slots
-
-    def _credit_free(self, t: Task):
-        self._free["n"] += t.slots
-        name = t.meta.get("pilot")
-        if name in self._free_by:
-            self._free_by[name] += t.slots
-
-    def _credit_free_n(self, rt: PilotRuntime, n: int):
-        self._free["n"] += n
-        name = getattr(rt, "_fleet_name", None)
-        if name in self._free_by:
-            self._free_by[name] += n
-
     def _can_launch_real(self, t: Task) -> bool:
-        name = self._dispatch(t, self._free_by)
+        name = self._dispatch(t, self._pilot_free())
         if name is None:
             return False
         t.meta["pilot"] = name        # late binding happens HERE
@@ -153,12 +126,10 @@ class FederatedSession(RuntimeSession):
             self.tracer.instant("dispatch", t.name, self._now(), pilot=name)
         return True
 
-    def _too_wide_sim(self, t: Task) -> bool:
+    def _too_wide(self, t: Task) -> bool:
         active = self.fleet.active().values()
         return (all(t.slots > rt.slots for rt in active)
                 if active else True)
-
-    _too_wide_real = _too_wide_sim
 
     def _fault_source(self):
         injectors = [rt.faults for rt in self.fleet.pilots.values()
@@ -169,40 +140,25 @@ class FederatedSession(RuntimeSession):
             return injectors[0]
         return _FaultUnion(injectors)
 
-    def _housekeeping_sim(self):
+    def _housekeeping(self):
         fleet = self.fleet
         self._sync_pilots()
         if fleet.recruiter is not None:
             # with an empty event heap the virtual clock only advances
             # here: jump to a pending recruit's arrival so starved tasks
             # wait for the incoming pilot instead of being canceled
-            if not self._heap and not self.graph.done():
+            if fleet.mode == "sim" and not self._heap \
+                    and not self.graph.done():
                 arrival = fleet.recruiter.next_arrival()
                 if arrival is not None:
                     self.vnow = max(self.vnow, arrival)
-            fleet.recruiter.tick(fleet, self, self.vnow)
+            fleet.recruiter.tick(fleet, self, self._now())
             self._sync_pilots()
         for rt in fleet.pilots.values():
             if rt.on_schedule is not None:
-                rt.on_schedule(rt, self.graph, self.vnow)
+                rt.on_schedule(rt, self.graph,
+                               self.vnow if fleet.mode == "sim" else None)
             rt._apply_resize()
-            rt._apply_topology_drop()
-            # resize/compaction changed rt.slots: dispatch reads it live,
-            # sim busy accounting needs no reconciliation
-
-    def _housekeeping_real(self):
-        fleet = self.fleet
-        self._sync_pilots()
-        if fleet.recruiter is not None:
-            fleet.recruiter.tick(fleet, self,
-                                 time.perf_counter() - self._t0)
-            self._sync_pilots()
-        for name, rt in fleet.pilots.items():
-            if rt.on_schedule is not None:
-                rt.on_schedule(rt, self.graph, None)
-            delta = rt._apply_resize()
-            if delta:
-                self._credit_free_n(rt, delta)
             rt._apply_topology_drop()
 
     # ------------------------------------------------------------ dispatch
@@ -255,38 +211,22 @@ class FederatedSession(RuntimeSession):
         return t.priority > 0 and any(
             rt.preempt for rt in self.fleet.active().values())
 
-    def _preempt_sim_for(self, t: Task) -> bool:
+    def _preempt_for(self, t: Task) -> bool:
         for name, rt in self.fleet.active().items():
             if not rt.preempt or t.slots > rt.slots:
                 continue
             need = t.slots - (rt.slots - self._busy_by.get(name, 0))
             victims = [] if need <= 0 else self._preempt_victims(
-                t, need, [v for v in self._sim_running_tasks()
+                t, need, [v for v in self._running()
                           if v.meta.get("pilot") == name])
             if victims is None:
                 continue
             for v in victims:
-                self._preempt_sim(v)
-            t.meta["pilot"] = name     # bind to the pilot we made room on
+                self._preempt(v)
+            # bind to the pilot we made room on (the real loop's
+            # _can_launch_real re-dispatches, and finds the same pilot)
+            t.meta["pilot"] = name
             return True
-        return False
-
-    def _preempt_real_for(self, t: Task) -> bool:
-        for name, rt in self.fleet.active().items():
-            if not rt.preempt or t.slots > rt.slots:
-                continue
-            need = t.slots - self._free_by.get(name, 0)
-            victims = [] if need <= 0 else self._preempt_victims(
-                t, need,
-                [v for (_, epoch), (_th, v) in self._live_attempts.items()
-                 if v.meta.get("launch_epoch") == epoch
-                 and v.state == TaskState.RUNNING
-                 and v.meta.get("pilot") == name])
-            if victims is None:
-                continue
-            for v in victims:
-                self._preempt_real(v)
-            return True              # _can_launch_real re-dispatches
         return False
 
     def _schedule_sim(self):
@@ -295,9 +235,7 @@ class FederatedSession(RuntimeSession):
             # before the min-width gate: a saturated fleet is exactly
             # when a latency task needs the eviction path
             self._preempt_pass_sim()
-        active = self.fleet.active()
-        free = {n: rt.slots - self._busy_by.get(n, 0)
-                for n, rt in active.items()}
+        free = self._pilot_free()
         widest = max(free.values(), default=0)
         min_w = graph.frontier_min_width()
         if min_w is None or min_w > widest:

@@ -21,9 +21,10 @@ ensemble B is still simulating.  ``PilotRuntime.run(graph)`` is now a thin
 wrapper: one session, one bulk submit, one drain.
 
 Fault tolerance (repro.runtime.faults): pod death is a NORMAL event, not an
-abort.  A ``FaultInjector`` kills pods on the run clock (virtual in sim,
-wall-clock elapsed in real); real mode additionally detects worker-thread
-death structurally and hung tasks via heartbeat staleness.  A pod loss
+abort.  A ``FaultInjector`` kills pods on the session clock (``_now``:
+virtual in sim, wall seconds since the first drain in real); real mode
+additionally detects worker-thread death structurally and hung tasks via
+heartbeat staleness.  A pod loss
 fails the in-flight attempts on that pod — each recorded in
 ``Task.history`` with the pod it ran on (the scitq Execution-table shape) —
 retires the pod's slot ids (capacity shrinks; with a device topology the
@@ -202,12 +203,12 @@ class PilotRuntime:
         with self._lock:
             self._resize_to = slots
 
-    def _apply_resize(self) -> int:
-        """Apply a pending resize; returns the capacity delta (real mode
-        must credit/debit its free-slot counter by it)."""
+    def _apply_resize(self):
+        """Apply a pending resize: ``slots`` takes the new value, and the
+        sessions derive their free capacity from it."""
         with self._lock:
             if self._resize_to is None:
-                return 0
+                return
             if self.topology is not None \
                     and self._resize_to > self.topology.n_slots:
                 # re-carve only when every live slot id is free: ids change
@@ -216,7 +217,7 @@ class PilotRuntime:
                 # ids of a dead pod must compact away first too
                 n_live = self.topology.n_slots - len(self._dead_ids)
                 if self._dead_ids or len(self._free_ids) < n_live:
-                    return 0
+                    return
                 self.topology = self.topology.recarve(self._resize_to)
                 self._free_ids = list(range(self.topology.n_slots))[::-1]
             delta = self._resize_to - self.slots
@@ -241,10 +242,8 @@ class PilotRuntime:
                     self._free_ids = [i for i in self._free_ids
                                      if i not in drop]
                     self._minted -= drop
-            delta_out = delta
             self.slots = self._resize_to
             self._resize_to = None
-            return delta_out
 
     # ------------------------------------------------------------ pods
     #: pod-name namespace for pilots WITHOUT a staging locality map —
@@ -427,13 +426,22 @@ class RuntimeSession:
     """Incremental scheduling over one pilot: ``submit()`` then ``drain()``.
 
     The session owns the live TaskGraph, the virtual clock (sim mode), and
-    the busy-slot accounting, all of which persist across submissions.  An
+    the busy-slot account, all of which persist across submissions.  An
     ``on_task_done(task, session)`` callback fires from inside the drain
     loop as each non-speculative task reaches a terminal state and may call
     :meth:`submit` to inject downstream work — dynamic injection is what
     turns the per-cycle barrier of the legacy plugins into streaming,
     per-pipeline progress.  Callbacks run on the drain thread; ``submit``
     is not thread-safe against a concurrent ``drain``.
+
+    Each clock keeps its own drain loop and event source: the sim loop
+    pops a heap of virtual finish times, the real loop runs one worker
+    thread per attempt under a condition variable.  The policies both
+    loops call are one set — launch (:meth:`_begin_attempt`), pod kill,
+    abandon, eviction, fault application, housekeeping and the
+    cancel-what-can-never-run rule — over one capacity account: busy
+    slots, with free capacity always ``rt.slots - _busy``.  In real mode
+    ``_busy`` changes only under ``_cv``.
     """
 
     def __init__(self, runtime: PilotRuntime, *, graph: Optional[TaskGraph]
@@ -445,15 +453,15 @@ class RuntimeSession:
         self.vnow = 0.0                      # virtual clock (sim mode)
         self._t0: Optional[float] = None     # real clock at first drain
         self._cbq: deque = deque()           # terminal tasks awaiting callback
-        # sim-mode state (persists across drains: the clock never resets)
-        self._busy = 0
+        self._busy = 0                       # slots held by live attempts
+        # the virtual clock's event source (persists across drains: the
+        # clock never resets)
         self._heap: List = []                # (v_finish, seq, epoch, task)
         self._seq = 0
         self._durations: Dict[str, List[float]] = {}
         self._spec_launched: Dict[str, Task] = {}
-        # real-mode state
+        # the wall clock's: worker threads under one condition variable
         self._cv = threading.Condition(threading.Lock())
-        self._free = {"n": runtime.slots}
         # workers still inside _execute_real: a task flips to a terminal
         # state BEFORE its completion bookkeeping (callback enqueue, slot
         # release) runs under the lock, so graph.done() alone must never
@@ -493,9 +501,7 @@ class RuntimeSession:
     def busy_slots(self) -> int:
         """Slots currently occupied by running tasks (live signal for
         adaptive strategies; reads the drain thread's own accounting)."""
-        if self.rt.mode == "sim":
-            return self._busy
-        return self.rt.slots - self._free["n"]
+        return self._busy
 
     # ------------------------------------------------------- observability
     def _now(self) -> float:
@@ -547,8 +553,8 @@ class RuntimeSession:
     # ------------------------------------------------------- dispatch hooks
     # Indirection points the federation layer (repro.federation) overrides
     # to route each task/pod to its owning pilot and to keep per-pilot
-    # capacity accounts.  The base session has exactly one pilot, so they
-    # all collapse to self.rt / the flat counters.
+    # busy accounts.  The base session has exactly one pilot, so they all
+    # collapse to self.rt / the flat account.
 
     def _rt_for(self, t: Task) -> PilotRuntime:
         """Runtime owning ``t``'s current attempt."""
@@ -560,56 +566,38 @@ class RuntimeSession:
         return self.rt
 
     def _occupy(self, t: Task):
-        """Charge ``t``'s width to the sim busy account at launch."""
+        """Charge ``t``'s width to the busy account at launch."""
         self._busy += t.slots
 
     def _vacate(self, t: Task):
-        """Return ``t``'s width to the sim busy account."""
+        """Return ``t``'s width to the busy account."""
         self._busy -= t.slots
 
     def _can_launch_real(self, t: Task) -> bool:
         """Capacity test for one real-mode launch (federation also binds
         the task to a pilot here)."""
-        return t.slots <= self._free["n"]
+        return t.slots <= self.rt.slots - self._busy
 
-    def _debit_free(self, t: Task):
-        self._free["n"] -= t.slots
-
-    def _credit_free(self, t: Task):
-        self._free["n"] += t.slots
-
-    def _credit_free_n(self, rt: PilotRuntime, n: int):
-        """Credit ``n`` slots of capacity belonging to ``rt`` (resize,
-        pod revival, kill-abandon deltas)."""
-        self._free["n"] += n
-
-    def _too_wide_sim(self, t: Task) -> bool:
+    def _too_wide(self, t: Task) -> bool:
         """True when no capacity this session will EVER have can host
-        ``t`` (the cancel-unsatisfiable rule's width half)."""
+        ``t`` (the cancel-unsatisfiable rule's width half).  Read only
+        when nothing is in flight, where free capacity is all of
+        ``rt.slots``."""
         return t.slots > self.rt.slots
-
-    def _too_wide_real(self, t: Task) -> bool:
-        return t.slots > self._free["n"]
 
     def _fault_source(self):
         """Injector consulted by the drain loops (federation: an
         aggregate over every pilot's injector)."""
         return self.rt.faults
 
-    def _housekeeping_sim(self):
-        """Per-pass sim housekeeping: strategy hook, pending resizes,
-        topology compaction."""
+    def _housekeeping(self):
+        """Per-pass housekeeping: strategy hook (``vnow`` None on the
+        wall clock), pending resizes, topology compaction."""
         rt = self.rt
         if rt.on_schedule is not None:
-            rt.on_schedule(rt, self.graph, self.vnow)
+            rt.on_schedule(rt, self.graph,
+                           self.vnow if rt.mode == "sim" else None)
         rt._apply_resize()
-        rt._apply_topology_drop()
-
-    def _housekeeping_real(self):
-        rt = self.rt
-        if rt.on_schedule is not None:
-            rt.on_schedule(rt, self.graph, None)
-        self._free["n"] += rt._apply_resize()   # elastic grow/shrink
         rt._apply_topology_drop()
 
     # ------------------------------------------------------------ submit
@@ -720,37 +708,41 @@ class RuntimeSession:
             self.on_task_done(self._cbq.popleft(), self)
 
     # ------------------------------------------------------------ failures
+    def _running(self) -> List[Task]:
+        """Live attempts on the session clock: heap entries (sim) or
+        worker threads (real) whose launch epoch is still the task's."""
+        if self.rt.mode == "sim":
+            live = ((epoch, t) for _, _, epoch, t in self._heap)
+        else:
+            live = ((epoch, t) for (_, epoch), (_th, t)
+                    in self._live_attempts.items())
+        return [t for epoch, t in live
+                if t.meta.get("launch_epoch") == epoch
+                and t.state == TaskState.RUNNING]
+
     def _pick_victim(self) -> Optional[str]:
         """Deterministic kill-victim choice when the injector names none:
         the busiest live pod (most running attempts; lowest name breaks
         ties), falling back to the first live pod."""
-        rt = self.rt
         counts: Dict[str, int] = {}
-        if rt.mode == "sim":
-            running = (t for _, _, epoch, t in self._heap
-                       if t.meta.get("launch_epoch") == epoch
-                       and t.state == TaskState.RUNNING)
-        else:
-            running = (t for _, t in self._live_attempts.values()
-                       if t.state == TaskState.RUNNING)
-        for t in running:
+        for t in self._running():
             tr = self._rt_for(t)
             p = tr._task_pod(t)
             if p is not None and p not in tr.dead_pods:
                 counts[p] = counts.get(p, 0) + 1
         if counts:
             return max(sorted(counts), key=lambda p: counts[p])
-        live = rt.live_pods()
+        live = self.rt.live_pods()
         return live[0] if live else None
 
-    def _revive_pod(self, pod: str) -> int:
+    def _revive_pod(self, pod: str):
         """A replacement pod joins under the dead pod's slot ids (fresh
-        pod: no data replicas — staging dropped them at the kill).
-        Returns the capacity gained (real mode credits its free count)."""
+        pod: no data replicas — staging dropped them at the kill); its
+        slots return to the pilot's capacity."""
         rt, prof = self._rt_for_pod(pod), self.prof
         ids = rt._dead_pod_ids.pop(pod, None)
         if not ids:
-            return 0
+            return
         rt.dead_pods.discard(pod)
         rt._dead_ids.difference_update(ids)
         if rt._minted is not None:
@@ -766,23 +758,148 @@ class RuntimeSession:
                                 pod=pod, n_slots=len(ids))
         prof.events.append({"event": "pod_revived", "pod": pod,
                             "n_slots": len(ids), "v": self.vnow})
-        return len(ids)
 
-    # ------------------------------------------------------------ sim mode
-    def _overhead(self, fn):
-        t0 = time.perf_counter()
-        out = fn()
-        self.prof.t_rts_overhead += time.perf_counter() - t0
-        return out
+    def _apply_faults(self):
+        """Fire the injector events due on the session clock: replacement
+        pods join, and pods die (the named pod, else the busiest)."""
+        faults = self._fault_source()
+        if faults is None:
+            return
+        for kind, pod in faults.pop_due(self._now()):
+            if kind == REVIVE:
+                self._revive_pod(pod)
+                continue
+            victim = pod if pod is not None else self._pick_victim()
+            if victim is not None \
+                    and victim not in self._rt_for_pod(victim).dead_pods:
+                self._kill_pod(victim)
 
-    def _launch_sim(self, t: Task):
-        self._occupy(t)
+    def _kill_pod(self, pod: str):
+        """Pod death: retire its slot ids (capacity shrinks), abandon the
+        attempts running on it, drop its staged replicas, and schedule a
+        replacement pod (or, with a topology, a shrink-recarve)."""
+        rt, prof, now = self._rt_for_pod(pod), self.prof, self._now()
+        ids = rt._pod_ids(pod)
+        if not ids:
+            return
+        idset = set(ids)
+        rt._retire_ids(ids, pod)
+        rt.slots = max(rt.slots - len(ids), 0)
+        # slot ids are pilot-local integers, so the victim scan must also
+        # match the owning runtime — id 3 on another pilot is a bystander
+        for t in self._running():
+            if self._rt_for(t) is rt \
+                    and idset.intersection(t.meta.get("slot_ids", ())):
+                self._abandon(t, pod, "pod_lost")
+        if rt.staging is not None:
+            rt.staging.on_pod_lost(pod)
+        # the virtual clock stamps the journal record with its time and
+        # the profile event as "v"; the wall clock as "elapsed"
+        sim = self.rt.mode == "sim"
+        rt.journal.record_event("pod_lost", pod=pod, n_slots=len(ids),
+                                **({"v": now} if sim else {}))
+        if self.tracer is not None:
+            self.tracer.instant("pod", f"pod_lost:{pod}", now,
+                                pod=pod, n_slots=len(ids))
+        prof.events.append({"event": "pod_lost", "pod": pod,
+                            "n_slots": len(ids),
+                            "v" if sim else "elapsed": now})
+        if rt.faults is not None and rt.faults.respawn_after is not None:
+            rt.faults.schedule_revive(pod, now)
+        elif rt.topology is not None:
+            rt._drop_pending = True
+
+    def _end_attempt(self, t: Task):
+        """End ``t``'s live attempt before its completion (abandon or
+        eviction) and return its capacity.  The cleared launch epoch turns
+        the completion that still arrives into an inert zombie: a stale
+        heap entry on the virtual clock; on the wall clock a worker thread
+        that cannot be stopped, whose live-attempt entry goes here."""
+        if self.rt.mode == "real":
+            entry = self._live_attempts.pop(
+                (t.name, t.meta.get("launch_epoch")), None)
+            if entry is not None:
+                self._zombie_threads.add(entry[0])
+            self._inflight -= 1
+        t.meta["launch_epoch"] = None
+        self._vacate(t)
+        self._rt_for(t)._release_slots(t)
+
+    def _abandon(self, t: Task, pod: Optional[str], reason: str):
+        """Fail one in-flight attempt (``pod_lost``; on the wall clock also
+        ``worker_died`` and ``heartbeat_timeout``): end it, record it
+        against the pod, and retry it off that pod or fail it."""
+        rt, prof = self._rt_for(t), self.prof
+        self._end_attempt(t)
+        err = f"{reason}: pod {pod}" if pod else reason
+        if self.rt.mode == "sim":
+            err += f" died at v={self.vnow:g}"
+        t.record_attempt(reason, pod=pod, error=err)
+        t.error = err
+        prof.n_pod_lost += 1
+        rt.journal.record(t, reason, pod=pod)
+        if self.tracer is not None:       # truncated span, never an overlap
+            self.tracer.task_end(t, self._now(), reason)
+        if t.speculative_of is not None:
+            # a clone needs no retry — the original is still running
+            t.state = TaskState.CANCELED
+            rt.journal.record(t, "canceled", by="pod_lost")
+            rt._staging_finish(t)
+            prof.t_data += t.t_data
+            self._spec_launched.pop(t.speculative_of, None)
+            return
+        t.meta.pop("slot_ids", None)
+        t.meta.pop("slots_released", None)
+        if t.attempts <= rt.max_retries:
+            t.state = TaskState.NEW     # re-enters the frontier; the next
+            prof.n_retries += 1         # grant excludes this pod
+        else:
+            t.state = TaskState.FAILED
+            t.v_finished = self.vnow
+            rt.journal.record(t, "failed", pod=pod)
+            rt._staging_finish(t)
+            prof.t_data += t.t_data
+            self._queue_callback(t)
+
+    def _cancel(self, t: Task):
+        tr = self._rt_for(t)
+        t.state = TaskState.CANCELED
+        tr.journal.record(t, "canceled")
+        tr._staging_finish(t)
+        self._queue_callback(t)
+
+    def _cancel_unsatisfiable(self, reviving: bool) -> bool:
+        """Nothing runnable: cancel only the tasks that can never run —
+        a failed or canceled dependency, or wider than any capacity the
+        session will have (deferred while a pod respawn is pending: the
+        capacity is coming back) — so a narrow task queued behind a
+        too-wide one still runs on the next pass.  True if any was."""
+        graph = self.graph
+        canceled = False
+        for t in graph.tasks.values():
+            if t.state == TaskState.NEW and (
+                    (self._too_wide(t) and not reviving) or any(
+                        graph.tasks[d].state.terminal
+                        and graph.tasks[d].state != TaskState.DONE
+                        for d in t.deps)):
+                self._cancel(t)
+                canceled = True
+        return canceled
+
+    # ------------------------------------------------------------ launch
+    def _begin_attempt(self, t: Task) -> float:
+        """Launch bookkeeping of one attempt, shared by both launchers:
+        capacity and slot-id grant, the attempt and its launch epoch, the
+        ``scheduled`` record and the attempt's span.  On the virtual clock
+        the staged-input transfers run here, between the grant and the
+        record, and extend the attempt; their seconds are returned (a real
+        attempt stages in on its worker, and 0.0 is returned)."""
         rt = self._rt_for(t)
+        self._occupy(t)
         rt._acquire_slots(t)
-        # staged-input transfers execute here — between pop_ready and
-        # launch — and extend the task's occupancy on the virtual clock
-        t_data = rt._stage_in_task(t)
-        t.meta["t_data_attempt"] = t_data   # this attempt's staged seconds
+        t_data = 0.0
+        if self.rt.mode == "sim":
+            t_data = t.meta["t_data_attempt"] = rt._stage_in_task(t)
         t.attempts += 1
         t.error = None                 # a retry must not inherit the
         t.state = TaskState.RUNNING    # previous attempt's error
@@ -800,7 +917,82 @@ class RuntimeSession:
                 extra["v_ready"] = v_ready
             journal.record(t, "scheduled", pod=pod, **extra)
         if self.tracer is not None:
-            self.tracer.task_begin(t, self.vnow, pod, t_data)
+            self.tracer.task_begin(t, self._now(), pod, t_data)
+        return t_data
+
+    # ------------------------------------------------------- preemption
+    # A ready high-priority task (serving's `latency` SLA class) that
+    # cannot fit may evict running lower-priority idempotent attempts.
+    # Eviction IS the abandon path: invalidate the launch epoch (the
+    # in-flight completion becomes an inert zombie), free capacity,
+    # record the attempt, requeue as NEW.  Unlike a pod failure it never
+    # blames the pod (excluded_pods ignores "preempted") and never
+    # consumes retry budget — a throughput task preempted N times still
+    # has its full max_retries for real failures.
+
+    def _preempt_enabled(self, t: Task) -> bool:
+        """Gate for one preemption attempt on behalf of ready task ``t``
+        (federation overrides: any active pilot that preempts)."""
+        return self.rt.preempt and t.priority > 0
+
+    def _preempt_victims(self, t: Task, need: int,
+                         running) -> Optional[List[Task]]:
+        """Pick victims freeing >= ``need`` slots for ``t``: strictly
+        lower priority, idempotent, not speculation-involved.  Least
+        work lost first (latest v_started).  None when the eligible pool
+        cannot cover the deficit — then nothing is evicted."""
+        cands = [v for v in running
+                 if (v.priority < t.priority and v.idempotent
+                     and v.speculative_of is None
+                     and v.name not in self._spec_launched)]
+        cands.sort(key=lambda v: (v.priority, -v.v_started, v.tid))
+        chosen, freed = [], 0
+        for v in cands:
+            chosen.append(v)
+            freed += v.slots
+            if freed >= need:
+                return chosen
+        return None
+
+    def _preempt_for(self, t: Task) -> bool:
+        """Free enough capacity for ``t`` by eviction; True when ``t``
+        fits afterwards (possibly without evicting anything).  The real
+        loop calls it holding the session cv."""
+        need = t.slots - (self.rt.slots - self._busy)
+        if need <= 0:
+            return True
+        victims = self._preempt_victims(t, need, self._running())
+        if victims is None:
+            return False
+        for v in victims:
+            self._preempt(v)
+        return True
+
+    def _preempt(self, v: Task):
+        """Evict one running attempt (:meth:`_abandon` minus the failure
+        semantics)."""
+        rt = self._rt_for(v)
+        self._end_attempt(v)
+        pod = rt._task_pod(v)
+        v.record_attempt("preempted", pod=pod)
+        self.prof.n_preempted += 1
+        rt.journal.record(v, "preempted", pod=pod)
+        if self.tracer is not None:
+            self.tracer.task_end(v, self._now(), "preempted")
+        v.meta.pop("slot_ids", None)
+        v.meta.pop("slots_released", None)
+        v.error = None
+        v.state = TaskState.NEW        # always requeues: not a failure
+
+    # ------------------------------------------------------------ sim mode
+    def _overhead(self, fn):
+        t0 = time.perf_counter()
+        out = fn()
+        self.prof.t_rts_overhead += time.perf_counter() - t0
+        return out
+
+    def _launch_sim(self, t: Task):
+        t_data = self._begin_attempt(t)
         heapq.heappush(self._heap,
                        (self.vnow + max(t.duration, 0.0) + t_data,
                         self._seq, t.attempts, t))
@@ -895,154 +1087,6 @@ class RuntimeSession:
                 prof.t_data += twin.t_data
             self._queue_callback(t)
 
-    def _apply_faults_sim(self):
-        for kind, pod in self._fault_source().pop_due(self.vnow):
-            if kind == REVIVE:
-                self._revive_pod(pod)
-            else:
-                victim = pod if pod is not None else self._pick_victim()
-                if victim is None \
-                        or victim in self._rt_for_pod(victim).dead_pods:
-                    continue
-                self._kill_pod_sim(victim)
-
-    def _kill_pod_sim(self, pod: str):
-        rt, prof = self._rt_for_pod(pod), self.prof
-        ids = rt._pod_ids(pod)
-        if not ids:
-            return
-        idset = set(ids)
-        rt._retire_ids(ids, pod)
-        rt.slots = max(rt.slots - len(ids), 0)
-        # slot ids are pilot-local integers, so the victim scan must also
-        # match the owning runtime — id 3 on another pilot is a bystander
-        victims = [t for _, _, epoch, t in self._heap
-                   if t.meta.get("launch_epoch") == epoch
-                   and t.state == TaskState.RUNNING
-                   and self._rt_for(t) is rt
-                   and idset.intersection(t.meta.get("slot_ids", ()))]
-        for t in victims:
-            self._abandon_sim(t, pod)
-        if rt.staging is not None:
-            rt.staging.on_pod_lost(pod)
-        rt.journal.record_event("pod_lost", pod=pod, n_slots=len(ids),
-                                v=self.vnow)
-        if self.tracer is not None:
-            self.tracer.instant("pod", f"pod_lost:{pod}", self.vnow,
-                                pod=pod, n_slots=len(ids))
-        prof.events.append({"event": "pod_lost", "pod": pod,
-                            "n_slots": len(ids), "v": self.vnow})
-        if rt.faults is not None and rt.faults.respawn_after is not None:
-            rt.faults.schedule_revive(pod, self.vnow)
-        elif rt.topology is not None:
-            rt._drop_pending = True
-
-    def _abandon_sim(self, t: Task, pod: str):
-        """Fail one in-flight sim attempt on a dead pod: invalidate its
-        launch epoch (the heap entry becomes a no-op), free its capacity,
-        record the attempt against the pod, and retry or fail."""
-        rt, prof = self._rt_for(t), self.prof
-        t.meta["launch_epoch"] = None
-        self._vacate(t)
-        rt._release_slots(t)
-        err = f"pod_lost: pod {pod} died at v={self.vnow:g}"
-        t.record_attempt("pod_lost", pod=pod, error=err)
-        t.error = err
-        prof.n_pod_lost += 1
-        rt.journal.record(t, "pod_lost", pod=pod)
-        if self.tracer is not None:       # truncated span, never an overlap
-            self.tracer.task_end(t, self.vnow, "pod_lost")
-        if t.speculative_of is not None:
-            # a clone needs no retry — the original is still running
-            t.state = TaskState.CANCELED
-            rt.journal.record(t, "canceled", by="pod_lost")
-            rt._staging_finish(t)
-            prof.t_data += t.t_data
-            self._spec_launched.pop(t.speculative_of, None)
-            return
-        t.meta.pop("slot_ids", None)
-        t.meta.pop("slots_released", None)
-        if t.attempts <= rt.max_retries:
-            t.state = TaskState.NEW     # re-enters the frontier; the next
-            prof.n_retries += 1         # grant excludes this pod
-        else:
-            t.state = TaskState.FAILED
-            t.v_finished = self.vnow
-            rt.journal.record(t, "failed", pod=pod)
-            rt._staging_finish(t)
-            prof.t_data += t.t_data
-            self._queue_callback(t)
-
-    # ------------------------------------------------------- preemption
-    # A ready high-priority task (serving's `latency` SLA class) that
-    # cannot fit may evict running lower-priority idempotent attempts.
-    # Eviction IS the abandon path: invalidate the launch epoch (the
-    # in-flight completion becomes an inert zombie), free capacity,
-    # record the attempt, requeue as NEW.  Unlike a pod failure it never
-    # blames the pod (excluded_pods ignores "preempted") and never
-    # consumes retry budget — a throughput task preempted N times still
-    # has its full max_retries for real failures.
-
-    def _preempt_enabled(self, t: Task) -> bool:
-        """Gate for one preemption attempt on behalf of ready task ``t``
-        (federation overrides: per-pilot capacity accounts need their own
-        victim arithmetic)."""
-        return self.rt.preempt and t.priority > 0
-
-    def _preempt_victims(self, t: Task, need: int,
-                         running) -> Optional[List[Task]]:
-        """Pick victims freeing >= ``need`` slots for ``t``: strictly
-        lower priority, idempotent, not speculation-involved.  Least
-        work lost first (latest v_started).  None when the eligible pool
-        cannot cover the deficit — then nothing is evicted."""
-        cands = [v for v in running
-                 if (v.priority < t.priority and v.idempotent
-                     and v.speculative_of is None
-                     and v.name not in self._spec_launched)]
-        cands.sort(key=lambda v: (v.priority, -v.v_started, v.tid))
-        chosen, freed = [], 0
-        for v in cands:
-            chosen.append(v)
-            freed += v.slots
-            if freed >= need:
-                return chosen
-        return None
-
-    def _sim_running_tasks(self) -> List[Task]:
-        return [v for _, _, epoch, v in self._heap
-                if v.meta.get("launch_epoch") == epoch
-                and v.state == TaskState.RUNNING]
-
-    def _preempt_sim_for(self, t: Task) -> bool:
-        """Free enough sim capacity for ``t`` by eviction; True when
-        ``t`` fits afterwards (possibly without evicting anything)."""
-        need = t.slots - (self.rt.slots - self._busy)
-        if need <= 0:
-            return True
-        victims = self._preempt_victims(t, need, self._sim_running_tasks())
-        if victims is None:
-            return False
-        for v in victims:
-            self._preempt_sim(v)
-        return True
-
-    def _preempt_sim(self, v: Task):
-        """Evict one running sim attempt (mirror of :meth:`_abandon_sim`
-        minus the failure semantics)."""
-        rt, prof = self._rt_for(v), self.prof
-        v.meta["launch_epoch"] = None
-        self._vacate(v)
-        rt._release_slots(v)
-        v.record_attempt("preempted", pod=rt._task_pod(v))
-        prof.n_preempted += 1
-        rt.journal.record(v, "preempted", pod=rt._task_pod(v))
-        if self.tracer is not None:
-            self.tracer.task_end(v, self.vnow, "preempted")
-        v.meta.pop("slot_ids", None)
-        v.meta.pop("slots_released", None)
-        v.error = None
-        v.state = TaskState.NEW        # always requeues: not a failure
-
     def _preempt_pass_sim(self):
         """Launch ready high-priority tasks, evicting for the ones that
         do not fit; runs before the normal scheduling pass so a latency
@@ -1055,7 +1099,7 @@ class RuntimeSession:
             if not self._preempt_enabled(t):
                 graph.requeue(t)
                 return
-            if self._preempt_sim_for(t):
+            if self._preempt_for(t):
                 self._launch_sim(t)
                 continue
             graph.requeue(t)           # nothing evictable: wait in line
@@ -1072,7 +1116,7 @@ class RuntimeSession:
             if _sample is not None and self.vnow != _sampled_at:
                 _sampled_at = self.vnow
                 _sample(_sampled_at)
-            self._housekeeping_sim()
+            self._housekeeping()
             self._overhead(self._schedule_sim)
 
             # fault events due before the next completion preempt it: a
@@ -1091,41 +1135,20 @@ class RuntimeSession:
                                  or (faults.pending_revive()
                                      and not graph.done())))):
                     self.vnow = max(self.vnow, nf)
-                    self._overhead(self._apply_faults_sim)
+                    self._overhead(self._apply_faults)
                     continue
 
             if not self._heap:
                 if graph.done():
                     break
-                # nothing runnable: cancel only truly unsatisfiable tasks
-                # (failed/canceled upstream, or wider than the whole pilot)
-                # so a narrow task queued behind a too-wide one still runs
-                # on the next pass — same rule as real mode.  A pending
-                # pod respawn defers the too-wide rule: capacity returns.
+                # nothing runnable: cancel what can never run
                 reviving = faults is not None and faults.pending_revive()
-                canceled = False
-                for t in graph.tasks.values():
-                    if t.state == TaskState.NEW and (
-                            (self._too_wide_sim(t) and not reviving) or any(
-                                graph.tasks[d].state.terminal
-                                and graph.tasks[d].state != TaskState.DONE
-                                for d in t.deps)):
-                        tr = self._rt_for(t)
-                        t.state = TaskState.CANCELED
-                        tr.journal.record(t, "canceled")
-                        tr._staging_finish(t)
-                        self._queue_callback(t)
-                        canceled = True
-                if not canceled and not reviving:
+                if not self._cancel_unsatisfiable(reviving) and not reviving:
                     # termination guard (unreachable by construction: a
                     # stuck NEW task always matches one rule above)
                     for t in graph.tasks.values():
                         if t.state == TaskState.NEW:
-                            tr = self._rt_for(t)
-                            t.state = TaskState.CANCELED
-                            tr.journal.record(t, "canceled")
-                            tr._staging_finish(t)
-                            self._queue_callback(t)
+                            self._cancel(t)
                 self._flush_callbacks()
                 if graph.done():
                     break
@@ -1209,142 +1232,21 @@ class RuntimeSession:
                     self._spec_launched[t.name] = dup
 
     # ------------------------------------------------------------ real mode
-    def _check_faults_real(self):
-        """Real-mode failure scan, run each pass of the drain loop: fire
-        due injector events (elapsed wall clock), then detect dead worker
-        threads — a thread that exited without running its completion
-        bookkeeping (e.g. SystemExit through the isolation boundary) —
-        and, with a detector configured, stale heartbeats."""
+    def _check_workers_real(self):
+        """Wall-clock failure detection, run each pass of the drain loop:
+        a worker thread that exited without running its completion
+        bookkeeping (e.g. SystemExit through the isolation boundary) and,
+        with a detector configured, a stale heartbeat."""
         now = time.perf_counter()
-        elapsed = now - self._t0
-        faults = self._fault_source()
-        if faults is not None:
-            for kind, pod in faults.pop_due(elapsed):
-                if kind == REVIVE:
-                    self._credit_free_n(self._rt_for_pod(pod),
-                                        self._revive_pod(pod))
-                else:
-                    victim = pod if pod is not None else self._pick_victim()
-                    if victim is not None and victim \
-                            not in self._rt_for_pod(victim).dead_pods:
-                        self._kill_pod_real(victim, elapsed)
         for (name, epoch), (th, t) in list(self._live_attempts.items()):
             if t.meta.get("launch_epoch") != epoch \
                     or t.state != TaskState.RUNNING:
                 continue
             tr = self._rt_for(t)
             if not th.is_alive():
-                self._abandon_real(t, tr._task_pod(t), "worker_died",
-                                   credit_slots=True)
+                self._abandon(t, tr._task_pod(t), "worker_died")
             elif tr.detector is not None and tr.detector.stale(t, now):
-                self._abandon_real(t, tr._task_pod(t), "heartbeat_timeout",
-                                   credit_slots=True)
-
-    def _kill_pod_real(self, pod: str, elapsed: float):
-        rt, prof = self._rt_for_pod(pod), self.prof
-        ids = rt._pod_ids(pod)
-        if not ids:
-            return
-        idset = set(ids)
-        rt._retire_ids(ids, pod)
-        abandoned_w = 0
-        for (name, epoch), (th, t) in list(self._live_attempts.items()):
-            if t.meta.get("launch_epoch") == epoch \
-                    and self._rt_for(t) is rt \
-                    and idset.intersection(t.meta.get("slot_ids", ())):
-                abandoned_w += t.slots
-                self._abandon_real(t, pod, "pod_lost", credit_slots=False)
-        rt.slots = max(rt.slots - len(ids), 0)
-        # the pod's free slots leave capacity; abandoned widths return
-        # (their surviving ids re-entered the id pool at release)
-        self._credit_free_n(rt, abandoned_w - len(ids))
-        if rt.staging is not None:
-            rt.staging.on_pod_lost(pod)
-        rt.journal.record_event("pod_lost", pod=pod, n_slots=len(ids))
-        if self.tracer is not None:
-            self.tracer.instant("pod", f"pod_lost:{pod}", elapsed,
-                                pod=pod, n_slots=len(ids))
-        prof.events.append({"event": "pod_lost", "pod": pod,
-                            "n_slots": len(ids), "elapsed": elapsed})
-        if rt.faults is not None and rt.faults.respawn_after is not None:
-            rt.faults.schedule_revive(pod, elapsed)
-        elif rt.topology is not None:
-            rt._drop_pending = True
-
-    def _abandon_real(self, t: Task, pod: Optional[str], reason: str, *,
-                      credit_slots: bool):
-        """Fail one in-flight real attempt (pod kill, dead worker thread,
-        stale heartbeat).  The worker thread cannot be stopped; popping
-        the live-attempt entry turns its eventual completion into a
-        zombie that skips all bookkeeping."""
-        rt, prof = self._rt_for(t), self.prof
-        entry = self._live_attempts.pop((t.name, t.meta.get("launch_epoch")),
-                                        None)
-        if entry is not None:
-            self._zombie_threads.add(entry[0])
-        t.meta["launch_epoch"] = None
-        self._inflight -= 1
-        if credit_slots:
-            self._credit_free(t)
-        rt._release_slots(t)
-        err = f"{reason}" + (f": pod {pod}" if pod else "")
-        t.record_attempt(reason, pod=pod, error=err)
-        t.error = err
-        prof.n_pod_lost += 1
-        rt.journal.record(t, reason, pod=pod)
-        if self.tracer is not None:
-            self.tracer.task_end(t, self._now(), reason)
-        t.meta.pop("slot_ids", None)
-        t.meta.pop("slots_released", None)
-        if t.attempts <= rt.max_retries:
-            t.state = TaskState.NEW
-            prof.n_retries += 1
-        else:
-            t.state = TaskState.FAILED
-            rt.journal.record(t, "failed", pod=pod)
-            prof.t_data += t.t_data
-            rt._staging_finish(t)
-            self._queue_callback(t)
-
-    def _preempt_real_for(self, t: Task) -> bool:
-        """Real-mode eviction on behalf of ready ``t`` (caller holds the
-        session cv).  The victim's worker thread cannot be stopped:
-        popping its live-attempt entry turns the eventual completion into
-        a zombie, exactly as the failure paths do."""
-        need = t.slots - self._free["n"]
-        if need <= 0:
-            return True
-        running = [v for (_, epoch), (_th, v) in self._live_attempts.items()
-                   if v.meta.get("launch_epoch") == epoch
-                   and v.state == TaskState.RUNNING]
-        victims = self._preempt_victims(t, need, running)
-        if victims is None:
-            return False
-        for v in victims:
-            self._preempt_real(v)
-        return True
-
-    def _preempt_real(self, v: Task):
-        """Evict one running real attempt (mirror of :meth:`_abandon_real`
-        minus the failure semantics)."""
-        rt, prof = self._rt_for(v), self.prof
-        entry = self._live_attempts.pop((v.name, v.meta.get("launch_epoch")),
-                                        None)
-        if entry is not None:
-            self._zombie_threads.add(entry[0])
-        v.meta["launch_epoch"] = None
-        self._inflight -= 1
-        self._credit_free(v)
-        rt._release_slots(v)
-        v.record_attempt("preempted", pod=rt._task_pod(v))
-        prof.n_preempted += 1
-        rt.journal.record(v, "preempted", pod=rt._task_pod(v))
-        if self.tracer is not None:
-            self.tracer.task_end(v, self._now(), "preempted")
-        v.meta.pop("slot_ids", None)
-        v.meta.pop("slots_released", None)
-        v.error = None
-        v.state = TaskState.NEW        # always requeues: not a failure
+                self._abandon(t, tr._task_pod(t), "heartbeat_timeout")
 
     def _execute_real(self, t: Task):
         rt, prof, cv = self._rt_for(t), self.prof, self._cv
@@ -1398,7 +1300,7 @@ class RuntimeSession:
             pod = rt._task_pod(t)
             if t.run is not None and outcome == TaskState.DONE:
                 t.result = res
-            self._credit_free(t)
+            self._vacate(t)
             rt._release_slots(t)
             # in-kernel lazy derefs (ctx["staging"].get) charged to t_data
             # come OUT of the exec window — the decomposition terms must
@@ -1458,22 +1360,13 @@ class RuntimeSession:
                     th.join()
 
     def _launch_real(self, t: Task, workers: List[threading.Thread]):
-        """Start one real-mode attempt (capacity already reserved via
-        :meth:`_can_launch_real`)."""
-        rt, graph = self._rt_for(t), self.graph
-        self._debit_free(t)
-        rt._acquire_slots(t)
+        """Start one real-mode attempt on its own worker thread (capacity
+        already checked by :meth:`_can_launch_real`)."""
+        graph = self.graph
         t.meta["dep_results"] = {
             d: graph.tasks[d].result for d in t.deps}
-        t.attempts += 1
-        t.error = None         # no stale error into a retry
-        t.state = TaskState.RUNNING
-        t.t_scheduled = time.perf_counter()
-        t.meta["launch_epoch"] = t.attempts
-        pod = rt._task_pod(t)
-        rt.journal.record(t, "scheduled", pod=pod, **self._sched_extra(t))
+        self._begin_attempt(t)
         if self.tracer is not None:
-            self.tracer.task_begin(t, self._now(), pod=pod)
             self._open_launch(t)
         self._inflight += 1
         th = threading.Thread(target=self._execute_real,
@@ -1510,8 +1403,9 @@ class RuntimeSession:
                 self._flush_callbacks()
                 if _sample is not None:
                     _sample(self._now())
-                self._housekeeping_real()
-                self._check_faults_real()
+                self._housekeeping()
+                self._apply_faults()
+                self._check_workers_real()
                 t0 = time.perf_counter()
                 # pop from the incremental frontier, re-checking capacity
                 # per task; too-wide tasks are skipped (narrower ones behind
@@ -1525,14 +1419,15 @@ class RuntimeSession:
                     # locality-ordered pass: input-local tasks claim free
                     # pods before tasks that would have to copy (too-wide
                     # candidates are skipped, as in the default pass)
-                    cands = self._locality_candidates(self._free["n"])
+                    cands = self._locality_candidates(
+                        rt.slots - self._busy)
                     cands.reverse()        # consumed via pop() below
                 while True:
                     if cands is not None:
                         t = cands.pop() if cands else None
                     else:
                         min_w = graph.frontier_min_width()
-                        if min_w is None or min_w > self._free["n"]:
+                        if min_w is None or min_w > rt.slots - self._busy:
                             t = None
                         else:
                             t = graph.pop_ready()
@@ -1549,7 +1444,7 @@ class RuntimeSession:
                         break
                     if not self._can_launch_real(t):
                         if self._preempt_enabled(t) \
-                                and self._preempt_real_for(t) \
+                                and self._preempt_for(t) \
                                 and self._can_launch_real(t):
                             scheduled.append(t)
                             self._launch_real(t, workers)
@@ -1565,28 +1460,12 @@ class RuntimeSession:
                 if graph.done() and quiescent:
                     break
                 if not scheduled and quiescent:
-                    # nothing runnable: cancel unsatisfiable tasks — failed
-                    # upstream deps, or wider than the whole idle pilot
-                    # (nothing in flight, so free == capacity: such a task
-                    # can never start and would spin this loop forever).
-                    # A pending pod respawn defers the too-wide rule:
-                    # the capacity is coming back.
+                    # nothing in flight, so free capacity is all of
+                    # rt.slots: cancel what can never run, or this loop
+                    # would spin forever
                     faults = self._fault_source()
-                    reviving = (faults is not None
-                                and faults.pending_revive())
-                    for t in graph.tasks.values():
-                        if t.state != TaskState.NEW:
-                            continue
-                        if (self._too_wide_real(t) and not reviving) \
-                                or any(
-                                graph.tasks[d].state.terminal
-                                and graph.tasks[d].state != TaskState.DONE
-                                for d in t.deps):
-                            tr = self._rt_for(t)
-                            t.state = TaskState.CANCELED
-                            tr.journal.record(t, "canceled")
-                            tr._staging_finish(t)
-                            self._queue_callback(t)
+                    self._cancel_unsatisfiable(
+                        faults is not None and faults.pending_revive())
                     if graph.done() and not self._cbq:
                         break
                     # retried tasks (back to NEW) reschedule next pass

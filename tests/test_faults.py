@@ -31,12 +31,31 @@ def bag(n, dur=10.0, stage="w"):
     return g
 
 
-# ---------------------------------------------------------------- sim kills
+def _journal_records(rt):
+    """Collect every record the runtime's journal emits (no file)."""
+    recs = []
+    rt.journal.observer = recs.append
+    return recs
 
-def test_sim_pod_kill_retries_off_dead_pod():
-    faults = FaultInjector(kill_at=[(5.0, "pod2")])
-    rt = PilotRuntime(slots=8, mode="sim", faults=faults, max_retries=2)
-    g = bag(8)
+
+def _kinds(recs, task=None):
+    """Event kinds of one task's records, or of the taskless ones."""
+    return [r["event"] for r in recs if r.get("task") == task]
+
+
+# ------------------------------------------------------- kills, both clocks
+# The pod-kill, abandon and retry policies are one code path on both
+# clocks: each case runs on the virtual clock and on the wall clock and
+# checks the same attempt outcomes and journal event kinds.  A real
+# attempt sleeps its duration, so the wall-clock case runs shorter.
+
+@pytest.mark.parametrize("mode", ["sim", "real"])
+def test_pod_kill_retries_off_dead_pod(mode):
+    dur, kill_at = {"sim": (10.0, 5.0), "real": (0.5, 0.1)}[mode]
+    faults = FaultInjector(kill_at=[(kill_at, "pod2")])
+    rt = PilotRuntime(slots=8, mode=mode, faults=faults, max_retries=2)
+    recs = _journal_records(rt)
+    g = bag(8, dur=dur)
     prof = rt.run(g)
 
     assert prof.n_failed == 0
@@ -51,13 +70,53 @@ def test_sim_pod_kill_retries_off_dead_pod():
     hist = {h["attempt"]: h for h in t.history}
     assert hist[1]["outcome"] == "pod_lost" and hist[1]["pod"] == "pod2"
     assert hist[2]["outcome"] == "done" and hist[2]["pod"] != "pod2"
-    # retry waited for a completion (v=10), then ran 10s on a live pod
-    assert prof.ttc == 20.0
+    assert _kinds(recs, t.name) == ["scheduled", "pod_lost", "scheduled",
+                                     "finished"]
+    assert _kinds(recs) == ["session_start", "pod_lost"]
+    # kept per clock: the virtual clock stamps its kill time on the
+    # taskless record and in the attempt's error
+    lost = next(r for r in recs if r["event"] == "pod_lost"
+                and "task" not in r)
+    if mode == "sim":
+        assert lost["v"] == 5.0
+        assert hist[1]["error"] == "pod_lost: pod pod2 died at v=5"
+        # retry waited for a completion (v=10), then ran 10s on a live pod
+        assert prof.ttc == 20.0
+    else:
+        assert "v" not in lost
+        assert hist[1]["error"] == "pod_lost: pod pod2"
     # the dead pod's id is retired; every surviving id returned exactly once
     assert rt.slots == 7
     assert sorted(rt._free_ids) == [0, 1, 3, 4, 5, 6, 7]
     assert rt.dead_pods == {"pod2"}
 
+
+@pytest.mark.parametrize("mode", ["sim", "real"])
+def test_pod_loss_failure_cancels_dependents(mode):
+    """Two kills exhaust a one-retry budget: the task fails on the second
+    pod, and its dependent is canceled, not left waiting."""
+    faults = FaultInjector(kill_at=[(0.1, "pod0"), (0.6, "pod1")])
+    rt = PilotRuntime(slots=3, mode=mode, faults=faults, max_retries=1)
+    recs = _journal_records(rt)
+    g = TaskGraph()
+    g.add(Task(name="a", duration=1.5))
+    g.add(Task(name="b", duration=1.5, deps=["a"]))
+    prof = rt.run(g)
+
+    a, b = g.tasks["a"], g.tasks["b"]
+    assert a.state == TaskState.FAILED and b.state == TaskState.CANCELED
+    assert [(h["outcome"], h["pod"]) for h in a.history] == [
+        ("pod_lost", "pod0"), ("pod_lost", "pod1")]
+    assert _kinds(recs, "a") == ["scheduled", "pod_lost", "scheduled",
+                                 "pod_lost", "failed"]
+    assert _kinds(recs, "b") == ["canceled"]
+    assert _kinds(recs) == ["session_start", "pod_lost", "pod_lost"]
+    assert (prof.n_failed, prof.n_canceled) == (1, 1)
+    assert (prof.n_pod_lost, prof.n_retries) == (2, 1)
+    assert rt.slots == 1
+
+
+# ---------------------------------------------------------------- sim kills
 
 def test_sim_pod_respawn_restores_capacity():
     faults = FaultInjector(kill_at=[(5.0, "pod2")], respawn_after=3.0)

@@ -329,6 +329,42 @@ def test_real_mode_federated_smoke():
     assert {t.meta["pilot"] for t in g.tasks.values()} <= {"p1", "p2"}
 
 
+def test_real_mode_federated_pod_kill_keeps_pilot_busy_within_slots():
+    """A wall-clock pod kill on one pilot: the victim's width leaves that
+    pilot's busy account as its slot leaves the capacity, so no pilot is
+    ever charged more than it holds, and the retry lands off the dead
+    pod."""
+    from repro.runtime.faults import FaultInjector
+
+    # staging on => slot-id tracking on, so pods are addressable to kill
+    fleet = build_fleet(2, slots=2, mode="real", max_retries=2)
+    fleet.pilots["p1"].faults = FaultInjector(kill_at=[(0.1, "p1:pod0")])
+    sess = fleet.session()
+    seen = []
+
+    def check():
+        seen.append({n: (sess.pilot_busy(n), rt.slots)
+                     for n, rt in fleet.pilots.items()})
+
+    occupy, kill = sess._occupy, sess._kill_pod
+    sess._occupy = lambda t: (occupy(t), check())
+    sess._kill_pod = lambda pod: (kill(pod), check())
+    sess.submit([Task(name=f"r{i}", duration=0.3) for i in range(8)])
+    prof = sess.drain()
+
+    assert prof.n_failed == 0 and prof.n_pod_lost == 1
+    assert all(t.state == TaskState.DONE for t in sess.graph.tasks.values())
+    assert fleet.pilots["p1"].slots == 1
+    assert seen and all(0 <= busy <= slots for snap in seen
+                        for busy, slots in snap.values())
+    assert {n: sess.pilot_busy(n) for n in fleet.pilots} == {"p1": 0,
+                                                             "p2": 0}
+    victim = next(t for t in sess.graph.tasks.values() if t.attempts == 2)
+    assert victim.history[0]["pod"] == "p1:pod0"
+    assert victim.history[-1]["pod"] != "p1:pod0"
+    fleet.close()
+
+
 # ------------------------------------------------------- static validator
 
 def _fleet_pipes(cores):

@@ -4,6 +4,7 @@ on-device Metropolis swap properties."""
 import json
 import os
 import tempfile
+import time
 
 import numpy as np
 import pytest
@@ -242,17 +243,31 @@ def test_real_mode_cancels_never_fitting_task():
     assert prof.n_canceled == 2         # cancellation is visible in profile
 
 
-def test_sim_mode_runs_narrow_task_behind_too_wide_one():
-    """Sim deadlock handling must cancel ONLY the unsatisfiable wide task;
-    an independent narrow task queued behind it still executes."""
-    rt = PilotRuntime(slots=2, mode="sim")
+@pytest.mark.parametrize("mode", ["sim", "real"])
+def test_narrow_task_runs_behind_too_wide_one(mode):
+    """Deadlock handling must cancel ONLY the unsatisfiable wide task;
+    an independent narrow task queued behind it still executes.  Both
+    clocks judge width against the pilot's slots, and read it only when
+    nothing holds a slot, where free slots are the same number."""
+    rt = PilotRuntime(slots=2, mode=mode)
     sess = rt.session()
+    seen = []
+    rule = sess._cancel_unsatisfiable
+
+    def spy(reviving):
+        seen.append(sess.busy_slots)
+        return rule(reviving)
+
+    sess._cancel_unsatisfiable = spy
     sess.submit([Task(name="wide", slots=4, duration=1.0),
-                 Task(name="narrow", slots=1, duration=2.0)])
+                 Task(name="narrow", slots=1, duration=2.0,
+                      run=lambda t: time.sleep(0.05))])
     prof = sess.drain()
     assert sess.graph.tasks["wide"].state == TaskState.CANCELED
     assert sess.graph.tasks["narrow"].state == TaskState.DONE
-    assert prof.ttc == 2.0
+    assert seen and set(seen) == {0}
+    if mode == "sim":
+        assert prof.ttc == 2.0
     assert prof.n_canceled == 1
 
 

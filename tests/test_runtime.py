@@ -188,26 +188,51 @@ def test_canceled_twin_bookkeeping():
     assert sorted(rt._free_ids) == list(range(8))       # twin's id returned
 
 
-def test_resize_takes_effect_mid_run():
-    """Elastic grow DURING a sim run (not between runs): the on_schedule
-    hook fires resize() once the first wave finished; later waves run at
-    the new width."""
+def _max_overlap(intervals):
+    """Most intervals open at once (an end at x closes before a start at
+    x opens)."""
+    edges = sorted([(a, 1) for a, _ in intervals]
+                   + [(b, -1) for _, b in intervals])
+    now = peak = 0
+    for _, d in edges:
+        now += d
+        peak = max(peak, now)
+    return peak
+
+
+@pytest.mark.parametrize("mode", ["sim", "real"])
+def test_resize_takes_effect_mid_run(mode):
+    """Elastic grow DURING a run (not between runs): the on_schedule hook
+    fires resize() once the first task finished; later waves run at the
+    new width, which free capacity (slots minus busy) admits at once on
+    both clocks.  A real attempt sleeps its duration."""
     fired = []
 
     def grow(rt, graph, vnow):
-        if vnow is not None and vnow >= 10.0 and not fired:
+        if not fired and any(t.state == TaskState.DONE
+                             for t in graph.tasks.values()):
             fired.append(vnow)
             rt.resize(4)
 
-    rt = PilotRuntime(slots=2, mode="sim", on_schedule=grow)
-    g = _graph([10.0] * 8)
-    prof = rt.run(g)
-    # wave1: 2 tasks @[0,10); resize at v=10; then 4-wide: 4 @[10,20),
-    # 2 @[20,30) -> makespan 30 (serial 2-wide would be 40)
-    assert fired and fired[0] == 10.0
-    assert prof.ttc == 30.0
+    rt = PilotRuntime(slots=2, mode=mode, on_schedule=grow)
+    dur = {"sim": 10.0, "real": 0.2}[mode]
+    sess = rt.session()
+    sess.submit([Task(name=f"t{i}", duration=dur) for i in range(8)])
+    prof = sess.drain()
     assert rt.slots == 4
-    assert all(t.state == TaskState.DONE for t in g.tasks.values())
+    assert all(t.state == TaskState.DONE for t in sess.graph.tasks.values())
+    assert sess.busy_slots == 0
+    tasks = sess.graph.tasks.values()
+    if mode == "sim":
+        # wave1: 2 tasks @[0,10); resize at v=10; then 4-wide: 4 @[10,20),
+        # 2 @[20,30) -> makespan 30 (serial 2-wide would be 40)
+        assert fired == [10.0]
+        assert prof.ttc == 30.0
+        spans = [(t.v_started, t.v_finished) for t in tasks]
+    else:
+        assert fired == [None]
+        spans = [(t.t_started, t.t_finished) for t in tasks]
+    assert _max_overlap(spans) == 4
 
 
 def test_resize_takes_effect_mid_run_real_mode():
@@ -257,6 +282,45 @@ def test_real_mode_never_oversubscribes():
     prof = PilotRuntime(slots=2, mode="real").run(g)
     assert prof.n_failed == 0
     assert concurrency["max"] <= 2
+
+
+def test_real_busy_account_balances_under_thread_churn():
+    """Stress: the busy account is written by the drain thread (launch)
+    and, under the same condition variable, by every worker (settle).
+    Many short attempts on more worker threads than cores, with a short
+    switch interval: a lost update would leave busy slots above zero
+    after the drain or let more attempts run than the pilot holds."""
+    import sys
+    import threading
+    import time as _time
+
+    lock = threading.Lock()
+    running = {"now": 0, "max": 0}
+
+    def work(t):
+        with lock:
+            running["now"] += 1
+            running["max"] = max(running["max"], running["now"])
+        _time.sleep(0.002)
+        with lock:
+            running["now"] -= 1
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        rt = PilotRuntime(slots=16, mode="real")
+        sess = rt.session()
+        sess.submit([Task(name=f"t{i}", run=work, slots=1 + i % 3)
+                     for i in range(300)])
+        t0 = _time.perf_counter()
+        prof = sess.drain()
+        assert _time.perf_counter() - t0 < 60.0
+    finally:
+        sys.setswitchinterval(interval)
+    assert prof.n_failed == 0
+    assert all(t.state == TaskState.DONE for t in sess.graph.tasks.values())
+    assert sess.busy_slots == 0
+    assert running["max"] <= 16
 
 
 def test_multislot_with_topology_grants_disjoint_submeshes():
@@ -322,31 +386,57 @@ def test_metropolis_host_vs_device():
 
 # ---------------------------------------------------------------- preemption
 
-def test_sim_preemption_evicts_lower_priority():
+@pytest.mark.parametrize("mode", ["sim", "real"])
+def test_preemption_requeues_victim_and_discards_zombie(mode):
     """A ready high-priority task that cannot fit evicts a running
     priority-0 attempt: eviction is the abandon path (epoch nulled, state
     NEW), not a failure (no pod blame, no retry spent), and the victim
-    reruns to completion afterwards."""
-    g = TaskGraph()
-    g.add(Task(name="starter", duration=1.0, stage="s"))
-    g.add(Task(name="lowA", duration=50.0, stage="s"))
-    g.add(Task(name="lowB", duration=50.0, stage="s"))
-    g.add(Task(name="hi", duration=5.0, slots=2, priority=10,
-               deps=["starter"], stage="s"))
-    prof = PilotRuntime(slots=2, mode="sim", preempt=True).run(g)
+    reruns to completion afterwards.  The evicted attempt's completion
+    still arrives (a stale heap entry on the virtual clock, a worker
+    thread that cannot be stopped on the wall clock) and is discarded:
+    the rerun's result is the one that lands.  Sim runs the durations,
+    real the callables."""
+    import threading
 
-    assert prof.n_preempted >= 1 and prof.n_failed == 0
+    release = threading.Event()
+    calls = []
+
+    def low_run(t):
+        n = len(calls)
+        calls.append(n)
+        release.wait(10.0)
+        return f"low{n}"
+
+    g = TaskGraph()
+    g.add(Task(name="starter", duration=1.0,
+               run=lambda t: __import__("time").sleep(0.05), stage="s"))
+    g.add(Task(name="lowA", duration=50.0, run=low_run, stage="s"))
+    g.add(Task(name="hi", duration=5.0, slots=2, priority=10,
+               deps=["starter"], run=lambda t: release.set() or "hi",
+               stage="s"))
+    rt = PilotRuntime(slots=2, mode=mode, preempt=True)
+    recs = []
+    rt.journal.observer = recs.append
+    prof = rt.run(g)
+
+    assert prof.n_preempted == 1 and prof.n_failed == 0
+    assert prof.n_retries == 0              # no retry budget spent
     assert all(t.state == TaskState.DONE for t in g.tasks.values())
     hi, lowA = g.tasks["hi"], g.tasks["lowA"]
-    # hi launched the moment it became ready, not after a 50s task
-    assert hi.v_started == 1.0 and hi.v_finished == 6.0
-    victims = [t for t in (g.tasks["lowA"], g.tasks["lowB"])
-               if any(h["outcome"] == "preempted" for h in t.history)]
-    assert victims
-    for v in victims:
-        assert v.attempts == 2              # evicted attempt + rerun
-        assert not v.excluded_pods()        # preemption never blames a pod
-        assert v.v_finished > hi.v_finished
+    assert lowA.attempts == 2               # evicted attempt + rerun
+    assert [h["outcome"] for h in lowA.history] == ["preempted", "done"]
+    assert not lowA.excluded_pods()         # preemption never blames a pod
+    assert [r["event"] for r in recs if r.get("task") == "lowA"] == [
+        "scheduled", "preempted", "scheduled", "finished"]
+    if mode == "sim":
+        # hi launched the moment it became ready, not after a 50s task;
+        # the evicted attempt's entry (v=50) did not finish lowA
+        assert hi.v_started == 1.0 and hi.v_finished == 6.0
+        assert lowA.v_finished == 56.0
+    else:
+        assert len(calls) == 2              # zombie attempt + rerun
+        assert lowA.result == "low1"        # zombie's "low0" was discarded
+        assert hi.result == "hi"
 
 
 def test_preempted_attempt_history_replays_from_journal():
@@ -368,36 +458,3 @@ def test_preempted_attempt_history_replays_from_journal():
         assert [h["outcome"] for h in history["lowA"]] == ["preempted"]
         assert history["lowA"][0]["attempt"] == 1
         assert sanitize_file(path).ok
-
-
-def test_real_preemption_discards_zombie_result():
-    """Real mode cannot stop the victim's worker thread; its eventual
-    completion must be an inert zombie (epoch mismatch) while the
-    requeued attempt's result is the one that lands."""
-    import threading
-
-    release = threading.Event()
-    calls = []
-
-    def low_run(t):
-        n = len(calls)
-        calls.append(n)
-        release.wait(10.0)
-        return f"low{n}"
-
-    g = TaskGraph()
-    g.add(Task(name="starter",
-               run=lambda t: __import__("time").sleep(0.05), stage="s"))
-    g.add(Task(name="lowA", run=low_run, stage="s"))
-    g.add(Task(name="hi", deps=["starter"], priority=10, slots=2,
-               run=lambda t: release.set() or "hi", stage="s"))
-    prof = PilotRuntime(slots=2, mode="real", preempt=True).run(g)
-
-    assert prof.n_preempted == 1 and prof.n_failed == 0
-    assert all(t.state == TaskState.DONE for t in g.tasks.values())
-    lowA = g.tasks["lowA"]
-    assert len(calls) == 2                  # zombie attempt + rerun
-    assert lowA.result == "low1"            # zombie's "low0" was discarded
-    assert [h["outcome"] for h in lowA.history[:1]] == ["preempted"]
-    assert not lowA.excluded_pods()
-    assert g.tasks["hi"].result == "hi"
